@@ -25,6 +25,7 @@ import numpy as np
 from repro.core.hardware import MachineSpec, V5E_VMEM_BYTES
 from repro.core.tpu_model import (
     DTYPE_BYTES,
+    LANE,
     SUBLANE,
     GemmShape,
     GridOrder,
@@ -40,7 +41,13 @@ from repro.core.tpu_model import (
 from repro.machines import registry as _machines
 
 # Candidate block dims: MXU-aligned multiples of 128 plus small sublane
-# multiples for skinny shapes.
+# multiples for skinny shapes.  A kernel clamps each block dim to the array
+# dim, and the TPU lowering accepts a block whose last dim is a multiple of
+# 128 (LANE) or the whole dim, and whose second-last dim is a multiple of 8
+# or the whole dim.  Every _CAND_MN entry is a multiple of 8 and every
+# _CAND_K entry a multiple of 128, so the one rule left to enforce is on bn:
+# it must be a multiple of the machine's block lane (``block_lane``) or
+# cover the whole N.
 _CAND_MN = (8, 16, 32, 64, 128, 256, 512, 1024, 2048)
 _CAND_K = (128, 256, 512, 1024, 2048)
 # Fraction of VMEM the kernel may claim (leave headroom for Mosaic spills,
@@ -48,12 +55,31 @@ _CAND_K = (128, 256, 512, 1024, 2048)
 VMEM_BUDGET_FRACTION = 0.75
 
 
+def vmem_budget(vmem_bytes: int | None = None) -> int:
+    """VMEM bytes one kernel may claim: the planner's feasibility bound and
+    the ``vmem_limit_bytes`` the Pallas kernels hand the compiler, so that
+    "feasible" means the same to both.  ``vmem_bytes`` defaults to the
+    ``tpu-v5e`` manifest's L1 (VMEM) capacity."""
+    if vmem_bytes is None:
+        vmem_bytes = _machines.get("tpu-v5e").capacity("L1")
+    return int(vmem_bytes * VMEM_BUDGET_FRACTION)
+
+
+def block_lane(machine: MachineSpec) -> int:
+    """The multiple a block's last dim must be unless it covers the dim:
+    the TPU lowering's 128 lanes where a vector register holds at least
+    that many, else the register width — which every lattice ``bn`` meets,
+    so machines the Pallas kernels never lower for keep the whole lattice."""
+    return min(LANE, machine.register_lanes)
+
+
 def candidate_tiles(
     shape: GemmShape,
     orders: Sequence[GridOrder] = (GridOrder.K_INNER, GridOrder.K_OUTER),
     vmem_bytes: int = int(V5E_VMEM_BYTES),
+    lane: int = LANE,
 ) -> list[TileConfig]:
-    budget = int(vmem_bytes * VMEM_BUDGET_FRACTION)
+    budget = vmem_budget(vmem_bytes)
     out = []
     for bm in _CAND_MN:
         if bm > shape.m and bm > 8:
@@ -63,6 +89,8 @@ def candidate_tiles(
         for bn in _CAND_MN:
             if bn > shape.n and bn > 128 and bn // 2 >= shape.n:
                 continue
+            if bn % lane and bn < shape.n:
+                continue                 # lowering refuses the block
             for bk in _CAND_K:
                 if bk > shape.k and bk > 128 and bk // 2 >= shape.k:
                     continue
@@ -126,17 +154,20 @@ def _lattice() -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
             np.array(bks, np.int64), np.array(inner, bool))
 
 
-def _feasible_mask(m, n, k, elem_bytes, vmem_bytes: int) -> np.ndarray:
+def _feasible_mask(m, n, k, elem_bytes, vmem_bytes: int,
+                   lane: int) -> np.ndarray:
     """(P, C) candidate-feasibility mask replaying ``candidate_tiles``'s
-    skip rules: one size past a short dim is allowed for padding, and the
+    skip rules: one size past a short dim is allowed for padding, a bn
+    off the ``lane`` multiple must cover the whole N, and the
     double-buffered working set must fit the VMEM budget."""
     bm, bn, bk, _ = _lattice()
-    budget = int(vmem_bytes * VMEM_BUDGET_FRACTION)
+    budget = vmem_budget(vmem_bytes)
     skip_m = (bm > m) & (bm > 8) & (bm // 2 >= m)
     skip_n = (bn > n) & (bn > 128) & (bn // 2 >= n)
     skip_k = (bk > k) & (bk > 128) & (bk // 2 >= k)
+    misaligned = (bn % lane != 0) & (bn < n)
     fits = vmem_required_batch(bm, bn, bk, elem_bytes) <= budget
-    return ~skip_m & ~skip_n & ~skip_k & fits
+    return ~skip_m & ~skip_n & ~skip_k & ~misaligned & fits
 
 
 def _solve_batch(shapes: Sequence[GemmShape], overlap: bool,
@@ -162,7 +193,8 @@ def _solve_batch(shapes: Sequence[GemmShape], overlap: bool,
         qr = np.array(ratios, np.float64)
         quant = (qr[:, 0:1], qr[:, 1:2], qr[:, 2:3])
 
-    mask = _feasible_mask(m, n, k, s_bytes, machine.capacity("L1"))
+    mask = _feasible_mask(m, n, k, s_bytes, machine.capacity("L1"),
+                          block_lane(machine))
     costs = estimate_batch(m, n, k, s_bytes, sub, peak, bm, bn, bk, inner,
                            accumulate=acc, machine=machine, quant=quant)
     totals = np.where(mask, costs.total(overlap), np.inf)
@@ -257,7 +289,8 @@ def tune_scalar(shape: GemmShape, overlap: bool = True,
     being an independent implementation ``tune_batch`` must agree with."""
     machine = machine or _machines.get("tpu-v5e")
     best: TileDecision | None = None
-    for t in candidate_tiles(shape, vmem_bytes=machine.capacity("L1")):
+    for t in candidate_tiles(shape, vmem_bytes=machine.capacity("L1"),
+                             lane=block_lane(machine)):
         d = TileDecision(shape=shape, tile=t,
                          cost=estimate(shape, t, machine), overlap=overlap)
         if best is None or d.seconds < best.seconds:

@@ -20,15 +20,9 @@ from typing import Mapping
 from repro.core.hardware import V5E_HBM_BW, V5E_ICI_BW, V5E_PEAK_BF16
 
 def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalised across jax versions: older
-    releases return a single-element list of dicts (one per partition),
-    newer ones a plain dict.  Returns ``{}`` when analysis is unavailable."""
-    ca = compiled.cost_analysis()
-    if ca is None:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        return dict(ca[0]) if ca else {}
-    return dict(ca)
+    """``compiled.cost_analysis()`` as a dict; ``{}`` when analysis is
+    unavailable."""
+    return dict(compiled.cost_analysis() or {})
 
 
 COLLECTIVE_OPS = (

@@ -37,6 +37,7 @@ from repro.gemm.api import (
 from repro.gemm.backends import dtype_tag
 from repro.gemm.planner import (
     backends,
+    cached_plans,
     clear_plan_cache,
     default_execute_backend,
     grouped_matmul,
@@ -56,7 +57,8 @@ __all__ = [
     "Backend", "GemmPlan", "GemmProblem", "NotExecutableError",
     "PrecisionConfig", "SweepResult", "SweepRow", "UnknownBackendError",
     "VariantChoice",
-    "backends", "clear_plan_cache", "default_execute_backend", "dtype_tag",
+    "backends", "cached_plans", "clear_plan_cache",
+    "default_execute_backend", "dtype_tag",
     "get_backend", "grouped_matmul", "matmul", "plan", "plan_cache_stats",
     "plan_many", "plan_model_gemms", "register_backend",
     "reset_plan_cache_stats", "save_cache", "sweep", "warm_cache",

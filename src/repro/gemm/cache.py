@@ -83,6 +83,9 @@ class PlanCache:
         self.stats = CacheStats()
         return old
 
+    def plans(self) -> list[GemmPlan]:
+        return list(self._plans.values())
+
     def clear(self) -> None:
         self._plans.clear()
         self.stats = CacheStats()

@@ -167,6 +167,13 @@ def plan_cache_stats(reset: bool = False) -> dict:
     return d
 
 
+def cached_plans(backend: str | None = None) -> list[GemmPlan]:
+    """The plans the process cache holds (on ``backend`` when given) — what
+    the code traced so far has planned."""
+    return [p for p in _CACHE.plans()
+            if backend is None or p.backend == backend]
+
+
 def reset_plan_cache_stats() -> None:
     """Zero the plan-cache counters without dropping cached plans."""
     _CACHE.reset_stats()
@@ -188,10 +195,15 @@ def save_cache(manifest_path: str) -> int:
 
 
 def default_execute_backend() -> str:
-    """The executable backend matching the ambient jax platform: Pallas on
-    TPU, the jnp reference elsewhere (keeps 512-device SPMD lowering clean —
-    DESIGN.md §3)."""
-    return "pallas" if jax.default_backend() == "tpu" else "reference"
+    """The executable backend for what the code can observe: Pallas on a
+    TPU, the jnp reference elsewhere and under an ambient mesh of more than
+    one device — the SPMD partitioner cannot split a Pallas kernel, and the
+    jnp dot keeps sharded lowering clean."""
+    from repro.runtime.sharding import ambient_mesh
+    if jax.default_backend() != "tpu":
+        return "reference"
+    mesh = ambient_mesh()
+    return "reference" if mesh is not None and mesh.size > 1 else "pallas"
 
 
 def matmul(x, w, *, backend: str | None = None, interpret: bool = False):

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+from repro.core.autotune import vmem_budget
 
 NEG_INF = -1e30
 
@@ -95,8 +95,9 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, block_q: int = 128,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_budget()),
         interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)

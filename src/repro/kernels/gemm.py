@@ -25,8 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
+from repro.core.autotune import vmem_budget
 from repro.core.tpu_model import GridOrder, TileConfig
 
 
@@ -73,8 +72,9 @@ def gemm_k_inner(a, b, *, tile: TileConfig, interpret: bool = False):
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_budget()),
         interpret=interpret,
     )(a, b)
 
@@ -103,8 +103,9 @@ def _k_step_call(m: int, n: int, bk: int, bm: int, bn: int,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.dtype(out_dtype)),
         input_output_aliases={2: 0},
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=vmem_budget()),
         interpret=interpret,
     )
     return jax.jit(call, donate_argnums=(2,) if donate else ())

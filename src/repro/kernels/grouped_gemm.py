@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+from repro.core.autotune import vmem_budget
 
 
 def _grouped_kernel(x_ref, w_ref, o_ref, acc_ref, *, k_steps: int):
@@ -53,8 +53,9 @@ def grouped_gemm_kernel(x, w, *, block_c: int = 128, block_f: int = 128,
         out_specs=pl.BlockSpec((1, bc, bf), lambda g, i, j, kk: (g, i, j)),
         out_shape=jax.ShapeDtypeStruct((e, c, f), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+                                 "arbitrary"),
+            vmem_limit_bytes=vmem_budget()),
         interpret=interpret,
     )(x, w)

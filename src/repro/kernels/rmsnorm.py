@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+from repro.core.autotune import vmem_budget
 
 
 def _rmsnorm_kernel(x_ref, scale_ref, o_ref, *, eps: float):
@@ -46,8 +46,9 @@ def rmsnorm(x, scale, *, eps: float = 1e-5, block_rows: int = 256,
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=vmem_budget()),
         interpret=interpret,
     )(x2, scale)
     return out.reshape(orig_shape)

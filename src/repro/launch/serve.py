@@ -3,6 +3,8 @@ model.
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b \\
         --requests 8 --max-new 12
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b --full \\
+        --requests 8 --max-new 16          # published widths (needs a chip)
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b \\
         --autoconfigure --machine gap9-fc --slo-p99 0.35 --rate 5 \\
         --trace /tmp/trace.json
@@ -19,6 +21,7 @@ import numpy as np
 from repro import obs
 from repro.configs import ARCH_IDS, get_config
 from repro.checkpoint.manager import CheckpointManager
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.common import HOST_MESH, split_params
 from repro.models.model import LM
 from repro.serving.engine import Request, ServingEngine
@@ -143,12 +146,17 @@ def serve_demo(arch: str, *, smoke: bool = True, n_requests: int = 8,
               f"({doc['metadata']['spans']} spans, "
               f"{doc['metadata']['events']} events; open in "
               f"chrome://tracing or ui.perfetto.dev)")
-    return {"requests": len(done), "tokens": toks, "seconds": dt}
+    return {"requests": len(done), "tokens": toks, "seconds": dt,
+            "prompts": {r.rid: list(r.prompt) for r in done},
+            "generated": {r.rid: list(r.generated) for r in done}}
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="qwen2-1.5b")
+    ap.add_argument("--full", dest="smoke", action="store_false",
+                    help="serve the published widths instead of the smoke "
+                         "config")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=12)
     ap.add_argument("--max-batch", type=int, default=4)
@@ -207,7 +215,8 @@ def main() -> None:
     elif a.faults is not None:
         ap.error("--faults needs --slo-p99 (robust autoconfiguration is "
                  "SLO attainment under perturbation)")
-    serve_demo(a.arch, n_requests=a.requests, max_new=a.max_new,
+    enable_compile_cache()
+    serve_demo(a.arch, smoke=a.smoke, n_requests=a.requests, max_new=a.max_new,
                max_batch=a.max_batch, max_len=a.max_len, ckpt_dir=a.ckpt_dir,
                autoconfigure=a.autoconfigure, machine=a.machine,
                memory=not a.no_memory, precisions=a.precision or (),

@@ -22,6 +22,7 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.configs import ARCH_IDS, get_config
 from repro.configs.base import ParallelConfig, ShapeConfig, TrainConfig
 from repro.data import DataIterator
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.common import HOST_MESH, split_params
 from repro.models.model import LM
 from repro.runtime.fault import StepWatchdog
@@ -100,6 +101,7 @@ def main() -> None:
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--seed", type=int, default=0)
     a = ap.parse_args()
+    enable_compile_cache()
     out = train(a.arch, smoke=a.smoke, steps=a.steps, batch=a.batch,
                 seq=a.seq, ckpt_dir=a.ckpt_dir, ckpt_every=a.ckpt_every,
                 lr=a.lr, microbatches=a.microbatches, seed=a.seed)

@@ -308,7 +308,9 @@ class LM:
         return x, aux_total, (caches_out if want_caches else None)
 
     # -- training -------------------------------------------------------------
-    def loss_fn(self, params, batch, *, remat="block"):
+    def logits(self, params, batch, *, remat=False):
+        """Full-sequence forward: (logits (B, S, V) over the token
+        positions, aux loss)."""
         cfg = self.cfg
         params = cast_for_compute(params, jnp.dtype(cfg.compute_dtype))
         x, prefix_len = self._embed_inputs(params, batch)
@@ -317,7 +319,11 @@ class LM:
         x = layers.apply_norm(params["final_norm"], x, cfg)
         if prefix_len:
             x = x[:, prefix_len:]
-        logits = layers.logits_head(params["embed"], x, cfg)
+        return layers.logits_head(params["embed"], x, cfg), aux
+
+    def loss_fn(self, params, batch, *, remat="block"):
+        cfg = self.cfg
+        logits, aux = self.logits(params, batch, remat=remat)
         loss = layers.cross_entropy(logits, batch["labels"], cfg.vocab_size,
                                     mask=batch.get("loss_mask"))
         return loss + aux, {"ce_loss": loss, "aux_loss": aux}

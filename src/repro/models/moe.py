@@ -252,14 +252,13 @@ def apply_moe_ep(params, x, cfg, mesh: MeshInfo):
         y = jax.vmap(gather_one)(ob, flat_e, safe_pos, keep, gate_vals)
         return y.astype(xs.dtype), aux
 
-    from jax.experimental.shard_map import shard_map
     from repro.runtime.sharding import ambient_mesh
     mesh_ctx = ambient_mesh()
     if mesh_ctx is None:
         raise RuntimeError(
             "apply_moe_ep needs an ambient mesh; wrap the call in "
             "`with repro.runtime.sharding.use_mesh(mesh):`")
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh_ctx,
         in_specs=(P(), P(mesh.model_axis, None, None),
@@ -267,7 +266,7 @@ def apply_moe_ep(params, x, cfg, mesh: MeshInfo):
                   P(mesh.model_axis, None, None),
                   P(dp, mesh.model_axis, None)),
         out_specs=(P(dp, mesh.model_axis, None), P()),
-        check_rep=False,
+        check_vma=False,
     )
     y, aux = fn(params["router"], params["w_gate"], params["w_up"],
                 params["w_down"], x)

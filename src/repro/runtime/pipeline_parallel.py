@@ -22,7 +22,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(block_fn: Callable, stage_params, x_micro, *,
@@ -76,8 +75,8 @@ def pipeline_apply(block_fn: Callable, stage_params, x_micro, *,
 
     in_specs = (P(axis), P())        # params sharded by stage; acts replicated
     out_specs = P()
-    fn = shard_map(stage_fn, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(stage_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return fn(stage_params, x_micro)
 
 

@@ -3,7 +3,6 @@ and per-arch parallelism defaults."""
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ParallelConfig, ShapeConfig
@@ -11,28 +10,14 @@ from repro.models.common import MeshInfo
 
 
 def use_mesh(mesh: Mesh):
-    """Ambient-mesh context manager across jax versions: ``jax.set_mesh``
-    where it exists, else the ``Mesh`` context manager (jax<0.7), which has
-    the same axis-name-resolution effect for pjit/with_sharding_constraint."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Context manager installing ``mesh`` as the ambient mesh."""
+    return jax.set_mesh(mesh)
 
 
-def ambient_mesh() -> Mesh | None:
-    """The mesh currently installed by :func:`use_mesh`, or None."""
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        m = get_abstract()
-        if m is not None and getattr(m, "axis_names", ()):
-            return m
-    thread_resources = getattr(jax.interpreters.pxla, "thread_resources",
-                               None)
-    if thread_resources is not None:
-        physical = thread_resources.env.physical_mesh
-        if not physical.empty:
-            return physical
-    return None
+def ambient_mesh():
+    """The (abstract) mesh installed by :func:`use_mesh`, or None."""
+    m = jax.sharding.get_abstract_mesh()
+    return m if m.axis_names else None
 
 
 def mesh_info(mesh: Mesh, fsdp: bool = False) -> MeshInfo:

@@ -100,14 +100,25 @@ def make_train_step(lm: LM, tcfg: TrainConfig, pcfg: ParallelConfig
 
 
 def init_train_state(lm: LM, tcfg: TrainConfig, key,
-                     pcfg: ParallelConfig | None = None):
-    """(param values, param specs, opt state, opt specs)."""
+                     pcfg: ParallelConfig | None = None, mesh=None):
+    """(param values, param specs, opt state, opt specs).
+
+    With ``mesh`` every array is created already sharded by its spec, so
+    the state is never whole on one device — at published widths it may
+    not fit one (qwen2-1.5b's f32 params + AdamW moments take ~18.6 GB)."""
     from repro.models.common import split_params
-    tree = lm.init(key)
-    values, specs = split_params(tree)
-    ocfg = make_adamw_config(lm.cfg, tcfg)
-    opt = init_opt_state(values, ocfg)
+    from repro.runtime.sharding import shardings_for
+    _, specs = split_params(jax.eval_shape(lm.init, key))
     ospecs = opt_state_specs(specs)
+    init_params = lambda k: split_params(lm.init(k))[0]  # noqa: E731
+    init_opt = functools.partial(init_opt_state,
+                                 cfg=make_adamw_config(lm.cfg, tcfg))
+    if mesh is not None:
+        init_params = jax.jit(init_params,
+                              out_shardings=shardings_for(mesh, specs))
+        init_opt = jax.jit(init_opt, out_shardings=shardings_for(mesh, ospecs))
+    values = init_params(key)
+    opt = init_opt(values)
     if pcfg is not None and pcfg.grad_compression == "int8_ef":
         opt["err"] = init_error_buffer(values)
         ospecs = dict(ospecs)

@@ -23,6 +23,7 @@ from repro.models.moe import (
     init_moe,
     padded_experts,
 )
+from repro.launch.mesh import make_host_mesh
 from repro.runtime.sharding import batch_specs, mesh_info, use_mesh
 
 pytestmark = pytest.mark.skipif(
@@ -30,7 +31,7 @@ pytestmark = pytest.mark.skipif(
 
 
 def _mesh24():
-    return jax.make_mesh((2, 4), ("data", "model"))
+    return make_host_mesh(2, 4)
 
 
 def test_moe_ep_matches_baseline_exactly():
@@ -120,7 +121,6 @@ def test_sharded_train_step_runs():
     from repro.configs.base import ParallelConfig, ShapeConfig, TrainConfig
     from repro.data import make_batch
     from repro.models.model import LM
-    from repro.runtime.sharding import shardings_for
     from repro.runtime.train_lib import init_train_state, make_train_step
 
     mesh = _mesh24()
@@ -130,10 +130,8 @@ def test_sharded_train_step_runs():
     tcfg = TrainConfig(lr=3e-3, warmup_steps=2, total_steps=10)
     shape = ShapeConfig("t", "train", 32, 8)
     with use_mesh(mesh):
-        params, pspecs, opt, ospecs = init_train_state(lm, tcfg,
-                                                       jax.random.key(0))
-        params = jax.device_put(params, shardings_for(mesh, pspecs))
-        opt = jax.device_put(opt, shardings_for(mesh, ospecs))
+        params, pspecs, opt, ospecs = init_train_state(
+            lm, tcfg, jax.random.key(0), mesh=mesh)
         step = jax.jit(make_train_step(lm, tcfg, ParallelConfig(fsdp=True)))
         losses = []
         for i in range(8):
@@ -142,3 +140,22 @@ def test_sharded_train_step_runs():
             losses.append(float(m["loss"]))
     assert losses[-1] < losses[0]
     assert np.isfinite(losses).all()
+    # the state was created sharded: an FSDP+model-sharded leaf spans the
+    # whole mesh
+    w = params["stack"]["b0_attn"]["mlp"]["w_up"]
+    assert len(w.sharding.device_set) == 8
+
+
+def test_default_execute_backend_under_a_mesh():
+    """On a TPU the planned GEMM runs the Pallas kernel, except under an
+    ambient mesh of more than one device: the SPMD partitioner cannot split
+    a Pallas kernel, so the sharded path runs the jnp reference."""
+    from unittest import mock
+    from repro import gemm
+    assert gemm.default_execute_backend() == "reference"      # the CPU
+    with mock.patch.object(jax, "default_backend", return_value="tpu"):
+        assert gemm.default_execute_backend() == "pallas"
+        with use_mesh(make_host_mesh(1, 1)):
+            assert gemm.default_execute_backend() == "pallas"
+        with use_mesh(_mesh24()):
+            assert gemm.default_execute_backend() == "reference"

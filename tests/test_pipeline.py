@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
 from repro.runtime.pipeline_parallel import pipeline_apply, split_stages
 
 pytestmark = pytest.mark.skipif(
@@ -47,7 +48,7 @@ def _sequential(params, x_micro):
 
 def test_pipeline_forward_matches_sequential():
     n_stages = 4
-    mesh = jax.make_mesh((n_stages,), ("pod",))
+    mesh = make_mesh((n_stages,), ("pod",))
     params, x = _setup()
     staged = split_stages(params, n_stages)
     got = pipeline_apply(_block, staged, x, mesh=mesh, axis="pod")
@@ -58,7 +59,7 @@ def test_pipeline_forward_matches_sequential():
 
 def test_pipeline_grads_match_sequential():
     n_stages = 4
-    mesh = jax.make_mesh((n_stages,), ("pod",))
+    mesh = make_mesh((n_stages,), ("pod",))
     params, x = _setup()
 
     def loss_pipe(p):
@@ -77,7 +78,7 @@ def test_pipeline_grads_match_sequential():
 
 
 def test_pipeline_two_stages():
-    mesh = jax.make_mesh((2,), ("pod",))
+    mesh = make_mesh((2,), ("pod",))
     params, x = _setup(n_layers=6, n_micro=3)
     staged = split_stages(params, 2)
     got = pipeline_apply(_block, staged, x, mesh=mesh, axis="pod")

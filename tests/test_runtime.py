@@ -341,3 +341,43 @@ def test_train_with_int8_ef_compression_converges():
     assert comp[-1] < comp[0] * 0.8          # still learns
     # compressed run tracks the plain run loosely
     assert abs(comp[-1] - plain[-1]) / plain[-1] < 0.5
+
+
+def test_compile_cache_defaults_to_checkout_root(monkeypatch):
+    """Unset JAX_COMPILATION_CACHE_DIR: the cache goes to <checkout>/.jax_cache."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from repro.launch.compile_cache import CHECKOUT_ROOT, enable_compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        path = enable_compile_cache()
+        assert path == str(CHECKOUT_ROOT / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert (CHECKOUT_ROOT / "chip_smoke.py").is_file()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+        compilation_cache.reset_cache()
+
+
+def test_compile_cache_env_dir_wins(tmp_path):
+    """Set JAX_COMPILATION_CACHE_DIR: the cache is written there, and the
+    checkout's .jax_cache is neither set nor created."""
+    import subprocess
+    import sys
+    from repro.launch.compile_cache import CHECKOUT_ROOT
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cc"),
+               PYTHONPATH=str(CHECKOUT_ROOT / "src"))
+    code = (
+        "import jax, jax.numpy as jnp\n"
+        "from repro.launch.compile_cache import enable_compile_cache\n"
+        "print(enable_compile_cache())\n"
+        "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)\n"
+        "jax.jit(lambda x: x * 2 + 1)(jnp.arange(8.0)).block_until_ready()\n"
+        "print(jax.config.jax_compilation_cache_dir)\n")
+    before = sorted((CHECKOUT_ROOT / ".jax_cache").glob("*"))
+    out = subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                         capture_output=True, text=True).stdout.split()
+    assert out == [str(tmp_path / "cc")] * 2
+    assert any((tmp_path / "cc").iterdir())
+    assert sorted((CHECKOUT_ROOT / ".jax_cache").glob("*")) == before
