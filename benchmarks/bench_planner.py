@@ -298,6 +298,22 @@ def bench_design_frontier() -> dict:
     }
 
 
+class _Untraced:
+    """A span's surface (context manager + ``set``) that does nothing: the
+    un-instrumented baseline of ``bench_obs_overhead``."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set(self, **attrs):
+        return self
+
+
 def bench_obs_overhead() -> dict:
     """Observability tax (repro.obs): the Table-2 GEMM sweep timed with
     span tracing disabled (the shipping default) against the same sweep
@@ -308,8 +324,6 @@ def bench_obs_overhead() -> dict:
     regressions show up per SHA too."""
     from repro import obs
     from repro.gemm import sweep
-    from repro.obs.trace import _NULL
-
     probs = [row.problem for row in TABLE2]
     reps_inner = 25  # one sweep is ~4ms; batch them so 2% is above noise
 
@@ -320,7 +334,8 @@ def bench_obs_overhead() -> dict:
 
     obs.disable()
     _, disabled_t = _best_of(run_sweeps, reps=5)
-    stub, orig = (lambda *a, **k: _NULL), obs.span
+    untraced = _Untraced()
+    stub, orig = (lambda *a, **k: untraced), obs.span
     try:
         obs.span = stub
         _, stub_t = _best_of(run_sweeps, reps=5)

@@ -178,11 +178,13 @@ class PallasBackend(AnalyticTpuBackend):
         t = plan.selection
         bm, bn, bk = min(t.bm, p.m), min(t.bn, p.n), min(t.bk, p.k)
         tile = TileConfig(bm, bn, bk, t.order)
-        ap = _pad_to(a, (bm, bk))
-        bp = _pad_to(b, (bk, bn))
-        cp = None if c is None else _pad_to(c, (bm, bn))
+        with jax.named_scope("gemm.prep"):
+            ap = _pad_to(a, (bm, bk))
+            bp = _pad_to(b, (bk, bn))
+            cp = None if c is None else _pad_to(c, (bm, bn))
         out = gemm_kernel.gemm(ap, bp, cp, tile=tile, interpret=interpret)
-        return out[:p.m, :p.n]
+        with jax.named_scope("gemm.prep"):
+            return out[:p.m, :p.n]
 
 
 class ReferenceBackend(Backend):

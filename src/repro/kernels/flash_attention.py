@@ -99,5 +99,6 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, block_q: int = 128,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=vmem_budget()),
         interpret=interpret,
+        name="flash_attention",
     )(qf, kf, vf)
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)

@@ -76,6 +76,7 @@ def gemm_k_inner(a, b, *, tile: TileConfig, interpret: bool = False):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=vmem_budget()),
         interpret=interpret,
+        name="gemm_k_inner",
     )(a, b)
 
 
@@ -107,6 +108,7 @@ def _k_step_call(m: int, n: int, bk: int, bm: int, bn: int,
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=vmem_budget()),
         interpret=interpret,
+        name="gemm_k_step",
     )
     return jax.jit(call, donate_argnums=(2,) if donate else ())
 

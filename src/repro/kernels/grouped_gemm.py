@@ -58,4 +58,5 @@ def grouped_gemm_kernel(x, w, *, block_c: int = 128, block_f: int = 128,
                                  "arbitrary"),
             vmem_limit_bytes=vmem_budget()),
         interpret=interpret,
+        name="grouped_gemm",
     )(x, w)

@@ -121,19 +121,21 @@ def init_embedding(key, cfg, mesh: MeshInfo, dtype):
 
 
 def embed_tokens(params, token_ids, cfg):
-    return jnp.take(params["table"], token_ids, axis=0)
+    with jax.named_scope("embed"):
+        return jnp.take(params["table"], token_ids, axis=0)
 
 
 def logits_head(params, x, cfg):
     """x: (..., d) -> (..., padded_vocab); soft-capped if configured."""
-    if cfg.tie_embeddings:
-        logits = gemm_api.matmul(x, params["table"].T)
-    else:
-        logits = gemm_api.matmul(x, params["unembed"])
-    if cfg.logit_softcap:
-        c = cfg.logit_softcap
-        logits = c * jnp.tanh(logits / c)
-    return logits
+    with jax.named_scope("head"):
+        if cfg.tie_embeddings:
+            logits = gemm_api.matmul(x, params["table"].T)
+        else:
+            logits = gemm_api.matmul(x, params["unembed"])
+        if cfg.logit_softcap:
+            c = cfg.logit_softcap
+            logits = c * jnp.tanh(logits / c)
+        return logits
 
 
 def cross_entropy(logits, labels, vocab_size: int, z_coef: float = 1e-4,

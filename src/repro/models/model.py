@@ -87,24 +87,29 @@ def _apply_block(params, kind: str, x, cfg, mesh, *, prefix_len=0):
     if kind in ("attn", "shared_attn", "moe"):
         h = layers.apply_norm(params["norm1"], x, cfg)
         b, s, _ = h.shape
-        positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-        q, k, v = attn._project_qkv(params["attn"], h, cfg, positions)
-        n_rep = q.shape[2] // k.shape[2]
-        out = attn.blockwise_attention(
-            q, attn._repeat_kv(k, n_rep), attn._repeat_kv(v, n_rep),
-            chunk=cfg.attn_chunk, causal=True, prefix_len=prefix_len)
-        x = x + jnp.einsum("bshe,hed->bsd", out, params["attn"]["wo"])
+        with jax.named_scope("attention"):
+            positions = jnp.broadcast_to(jnp.arange(s), (b, s))
+            q, k, v = attn._project_qkv(params["attn"], h, cfg, positions)
+            n_rep = q.shape[2] // k.shape[2]
+            out = attn.blockwise_attention(
+                q, attn._repeat_kv(k, n_rep), attn._repeat_kv(v, n_rep),
+                chunk=cfg.attn_chunk, causal=True, prefix_len=prefix_len)
+            out = jnp.einsum("bshe,hed->bsd", out, params["attn"]["wo"])
+        x = x + out
         cache = {"k": k, "v": v}
         if kind == "moe":
             h2 = layers.apply_norm(params["norm2"], x, cfg)
-            if moe.ep_applicable(cfg, mesh, h2.shape[1]):
-                y, aux = moe.apply_moe_ep(params["moe"], h2, cfg, mesh)
-            else:
-                y, aux = moe.apply_moe(params["moe"], h2, cfg, mesh)
+            with jax.named_scope("moe"):
+                if moe.ep_applicable(cfg, mesh, h2.shape[1]):
+                    y, aux = moe.apply_moe_ep(params["moe"], h2, cfg, mesh)
+                else:
+                    y, aux = moe.apply_moe(params["moe"], h2, cfg, mesh)
             x = x + y
         elif cfg.d_ff:
             h2 = layers.apply_norm(params["norm2"], x, cfg)
-            x = x + layers.apply_mlp(params["mlp"], h2, cfg)
+            with jax.named_scope("mlp"):
+                y = layers.apply_mlp(params["mlp"], h2, cfg)
+            x = x + y
         return x, aux, cache
     if kind == "mamba2":
         h = layers.apply_norm(params["norm1"], x, cfg)
@@ -124,16 +129,20 @@ def _apply_block(params, kind: str, x, cfg, mesh, *, prefix_len=0):
 def _decode_block(params, kind: str, cache, x, cfg, mesh, *, pos):
     if kind in ("attn", "shared_attn", "moe"):
         h = layers.apply_norm(params["norm1"], x, cfg)
-        out, cache = attn.decode_attention(params["attn"], cache, h, cfg,
-                                           mesh, pos=pos)
+        with jax.named_scope("attention"):
+            out, cache = attn.decode_attention(params["attn"], cache, h, cfg,
+                                               mesh, pos=pos)
         x = x + out
         if kind == "moe":
             h2 = layers.apply_norm(params["norm2"], x, cfg)
-            y, _ = moe.apply_moe(params["moe"], h2, cfg, mesh)
+            with jax.named_scope("moe"):
+                y, _ = moe.apply_moe(params["moe"], h2, cfg, mesh)
             x = x + y
         elif cfg.d_ff:
             h2 = layers.apply_norm(params["norm2"], x, cfg)
-            x = x + layers.apply_mlp(params["mlp"], h2, cfg)
+            with jax.named_scope("mlp"):
+                y = layers.apply_mlp(params["mlp"], h2, cfg)
+            x = x + y
         return x, cache
     if kind == "mamba2":
         h = layers.apply_norm(params["norm1"], x, cfg)
@@ -312,7 +321,8 @@ class LM:
         """Full-sequence forward: (logits (B, S, V) over the token
         positions, aux loss)."""
         cfg = self.cfg
-        params = cast_for_compute(params, jnp.dtype(cfg.compute_dtype))
+        with jax.named_scope("cast_weights"):
+            params = cast_for_compute(params, jnp.dtype(cfg.compute_dtype))
         x, prefix_len = self._embed_inputs(params, batch)
         x, aux, _ = self._run_stack(params, x, prefix_len=prefix_len,
                                     want_caches=False, remat=remat)
@@ -332,7 +342,8 @@ class LM:
     def prefill(self, params, batch):
         """Full-sequence forward; returns (last_logits, caches)."""
         cfg = self.cfg
-        params = cast_for_compute(params, jnp.dtype(cfg.compute_dtype))
+        with jax.named_scope("cast_weights"):
+            params = cast_for_compute(params, jnp.dtype(cfg.compute_dtype))
         x, prefix_len = self._embed_inputs(params, batch)
         x, _, caches = self._run_stack(params, x, prefix_len=prefix_len,
                                        want_caches=True, remat=False)
@@ -370,7 +381,8 @@ class LM:
         """token: (B, 1) int32 (or (B,1,D) frames for audio); pos: scalar.
         Returns (logits (B, V), new caches)."""
         cfg, mesh = self.cfg, self.mesh
-        params = cast_for_compute(params, jnp.dtype(cfg.compute_dtype))
+        with jax.named_scope("cast_weights"):
+            params = cast_for_compute(params, jnp.dtype(cfg.compute_dtype))
         period, k, tail = factor_pattern(cfg.block_pattern)
         if token.ndim == 3:  # audio frames passthrough
             x = frontends.apply_frontend(params["frontend"], token, cfg)

@@ -5,7 +5,9 @@ One vocabulary across every layer of plan → serve → simulate → calibrate:
 * :func:`span` / :func:`add_span` — nestable named intervals recorded by
   a process-local :class:`Recorder`, exported as Chrome-trace/Perfetto
   JSON (:func:`to_chrome_trace`, ``chrome://tracing`` / ui.perfetto.dev).
-  Disabled by default; :func:`enable` turns the span channel on.  The
+  Disabled by default; :func:`enable` turns the span channel on.  A live
+  ``span`` is also a ``jax.profiler.TraceAnnotation``, enabled or not, so
+  it shows on the host plane of any ``jax.profiler`` trace.  The
   serving engine's always-on ``repro.serving/trace-v1`` events flow
   through the same recorder, so ``ServingEngine.trace_json()`` is a view
   over it.
@@ -18,9 +20,10 @@ One vocabulary across every layer of plan → serve → simulate → calibrate:
   ``perf_report()``, ``SimReport`` and ``python -m repro.obs drift``.
 
 Overhead contract: with tracing disabled every ``obs.span(...)`` call
-site costs one method call returning a shared no-op — the
-``obs_overhead`` workload in ``benchmarks/bench_planner.py`` asserts
-<2% on the Table-2 sweep.  See docs/OBSERVABILITY.md.
+site costs one profiler annotation (about a microsecond with no profiler
+session) — the ``obs_overhead`` workload in
+``benchmarks/bench_planner.py`` asserts <2% on the Table-2 sweep.  See
+docs/OBSERVABILITY.md.
 """
 from repro.obs.drift import (
     DEFAULT_MAX_DRIFT,
@@ -47,7 +50,8 @@ metrics = MetricsRegistry()
 
 
 def span(name: str, *, track: str = "wall", **attrs):
-    """Open a span on the process recorder (no-op while disabled)."""
+    """Open a span: a profiler annotation, kept by the process recorder
+    while it is enabled."""
     return recorder.span(name, track=track, **attrs)
 
 

@@ -4,10 +4,12 @@ The subsystem has two channels with different on/off semantics:
 
 * **Spans** — nested, named intervals (``obs.span("gemm.sweep")``) emitted
   from the hot paths (planner, sweep, serving steps, simulator, calibrator
-  fits).  Spans are *disabled by default*: ``span()`` returns a shared
-  no-op singleton when the recorder is off, so an instrumented hot loop
-  pays one attribute load + one branch per call site (the
-  ``obs_overhead`` bench workload asserts <2% on the Table-2 sweep).
+  fits).  Every span is a ``jax.profiler.TraceAnnotation`` while it is
+  open, so it lands on the host plane of any profiler trace, on the clock
+  of the device planes; with no profiler session that costs about a
+  microsecond.  The recorder keeps spans in memory only while enabled
+  (*disabled by default*; the ``obs_overhead`` bench workload asserts <2%
+  on the Table-2 sweep).
 * **Events** — the serving engine's ``repro.serving/trace-v1`` payloads.
   These were always-on before ``repro.obs`` existed and stay always-on:
   the engine appends them through :meth:`Recorder.add_event` and
@@ -27,6 +29,8 @@ import dataclasses
 import json
 import time
 from typing import Any, Mapping
+
+from jax.profiler import TraceAnnotation
 
 #: Schema tag stamped on every Chrome-trace export's ``metadata`` block.
 TRACE_EXPORT_SCHEMA = "repro.obs/chrome-trace-v1"
@@ -54,49 +58,43 @@ class Span:
                 "attrs": dict(self.attrs)}
 
 
-class _NullSpan:
-    """Shared no-op returned by ``span()`` when tracing is disabled.
-
-    Implements just enough surface (context manager + ``set``) that call
-    sites never branch on enablement themselves.
-    """
+class _Annotation(TraceAnnotation):
+    """A profiler annotation with a span's surface: what ``span()`` returns
+    while the recorder is disabled."""
 
     __slots__ = ()
 
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
     def set(self, **attrs):
+        """Attach attributes to the annotation while it is open."""
+        self.set_metadata(**attrs)
         return self
-
-
-_NULL = _NullSpan()
 
 
 class _LiveSpan:
     """Context-manager handle for one recorder-backed span."""
 
-    __slots__ = ("_rec", "_span")
+    __slots__ = ("_rec", "_span", "_ann")
 
-    def __init__(self, rec: "Recorder", span: Span):
+    def __init__(self, rec: "Recorder", span: Span, ann: _Annotation):
         self._rec = rec
         self._span = span
+        self._ann = ann
 
     def __enter__(self):
+        self._ann.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         if exc_type is not None:
             self._span.attrs.setdefault("error", exc_type.__name__)
         self._rec._close(self._span)
+        self._ann.__exit__(exc_type, exc, tb)
         return False
 
     def set(self, **attrs):
         """Attach attributes to the span while it is open."""
         self._span.attrs.update(attrs)
+        self._ann.set(**attrs)
         return self
 
 
@@ -138,16 +136,18 @@ class Recorder:
     # -- span channel (gated on ``enabled``) ---------------------------------
 
     def span(self, name: str, *, track: str = "wall", **attrs):
-        """Open a nested span; no-op singleton when disabled."""
+        """Open a nested span: a profiler annotation always, kept in memory
+        as well while the recorder is enabled."""
+        ann = _Annotation(name, **attrs)
         if not self.enabled:
-            return _NULL
+            return ann
         s = Span(sid=self._next_sid, name=name, t0=self.clock(),
                  attrs=dict(attrs), track=track,
                  parent=self._stack[-1].sid if self._stack else None)
         self._next_sid += 1
         self.spans.append(s)
         self._stack.append(s)
-        return _LiveSpan(self, s)
+        return _LiveSpan(self, s, ann)
 
     def _close(self, span: Span) -> None:
         span.t1 = self.clock()

@@ -182,6 +182,8 @@ class ServingEngine:
         # must not pay O(n) per admission like list.pop(0) did.
         self.queue: collections.deque[Request] = collections.deque()
         self.finished: list[Request] = []
+        # steps begun: the ``step`` attribute of every ``serve.*`` span
+        self.steps = 0
         self._decode = jax.jit(self._decode_impl)
         self._prefill = {}
         self._insert = jax.jit(self._insert_impl, static_argnums=(2,),
@@ -472,11 +474,12 @@ class ServingEngine:
     # -- jitted pieces --------------------------------------------------------
     def _decode_impl(self, params, caches, tokens, pos_vec, active):
         logits, caches = self.lm.decode_step(params, caches, tokens, pos_vec)
-        logits = logits.astype(jnp.float32)
-        vp = logits.shape[-1]
-        if vp > self.lm.cfg.vocab_size:
-            logits = logits.at[..., self.lm.cfg.vocab_size:].set(-1e9)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            logits = logits.astype(jnp.float32)
+            vp = logits.shape[-1]
+            if vp > self.lm.cfg.vocab_size:
+                logits = logits.at[..., self.lm.cfg.vocab_size:].set(-1e9)
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return nxt, caches
 
     def _prefill_fn(self, bucket: int) -> Callable:
@@ -519,26 +522,28 @@ class ServingEngine:
                 accepted, the caller owns the retry (see
                 :func:`repro.serving.resilience.retry_with_backoff`).
         """
-        req.t_submit = time.perf_counter()
-        if self.queue_limit is not None \
-                and len(self.queue) >= self.queue_limit:
-            self.rejected_submits += 1
-            obs.metrics.counter("serving.rejected_submits")
-            self._trace({
-                "type": "reject", "rid": req.rid, "t": req.t_submit,
-                "queue_depth": len(self.queue), "limit": self.queue_limit})
-            raise QueueFullError(limit=self.queue_limit,
-                                 depth=len(self.queue))
-        self.queue.append(req)
-        obs.metrics.counter("serving.submitted")
-        event = {
-            "type": "submit", "rid": req.rid, "t": req.t_submit,
-            "prompt_len": len(req.prompt),
-            "max_new_tokens": req.max_new_tokens}
-        dl = self._deadline_for(req)
-        if dl is not None:
-            event["deadline_s"] = dl
-        self._trace(event)
+        with obs.span("serve.submit", step=self.steps, rid=req.rid):
+            req.t_submit = time.perf_counter()
+            if self.queue_limit is not None \
+                    and len(self.queue) >= self.queue_limit:
+                self.rejected_submits += 1
+                obs.metrics.counter("serving.rejected_submits")
+                self._trace({
+                    "type": "reject", "rid": req.rid, "t": req.t_submit,
+                    "queue_depth": len(self.queue),
+                    "limit": self.queue_limit})
+                raise QueueFullError(limit=self.queue_limit,
+                                     depth=len(self.queue))
+            self.queue.append(req)
+            obs.metrics.counter("serving.submitted")
+            event = {
+                "type": "submit", "rid": req.rid, "t": req.t_submit,
+                "prompt_len": len(req.prompt),
+                "max_new_tokens": req.max_new_tokens}
+            dl = self._deadline_for(req)
+            if dl is not None:
+                event["deadline_s"] = dl
+            self._trace(event)
 
     def _free_slots(self) -> list[int]:
         return [i for i, r in enumerate(self.slot_req) if r is None]
@@ -665,12 +670,14 @@ class ServingEngine:
                                 for k in self.lm.cfg.block_pattern)
                 bucket = (len(prefix) if recurrent
                           else min(_bucket(len(prefix)), self.max_len))
-                with obs.span("serve.prefill", rid=req.rid, bucket=bucket,
-                              slot=slot):
+                with obs.span("serve.prefill", step=self.steps, rid=req.rid,
+                              bucket=bucket, slot=slot):
                     toks = jnp.zeros((1, bucket), jnp.int32)
                     toks = toks.at[0, :len(prefix)].set(
                         jnp.array(prefix, jnp.int32))
                     pref = self._prefill_fn(bucket)(self.params, toks)
+                with obs.span("serve.insert", step=self.steps, rid=req.rid,
+                              slot=slot):
                     self.caches = self._insert(self.caches, pref, slot)
             self.slot_pos[slot] = len(ptoks) - 1
             self.slot_req[slot] = req
@@ -684,62 +691,79 @@ class ServingEngine:
 
     def step(self) -> list[Request]:
         """Admit + one decode step for all active slots; returns newly
-        finished requests."""
-        t_start = time.perf_counter()
-        admitted = self._admit()
-        active = [i for i, r in enumerate(self.slot_req) if r is not None]
-        self._update_ladder(len(active))
-        if not active:
-            return []
-        tokens = jnp.zeros((self.max_batch, 1), jnp.int32)
-        for i in active:
-            r = self.slot_req[i]
-            last = r.generated[-1] if r.generated else r.prompt[-1]
-            tokens = tokens.at[i, 0].set(last)
-        # inactive slots decode harmlessly at position 0 (outputs ignored;
-        # admission overwrites their cache region)
-        pos_vec = jnp.minimum(jnp.array(self.slot_pos, jnp.int32),
-                              self.max_len - 1)
-        active_mask = jnp.array([r is not None for r in self.slot_req])
-        nxt, self.caches = self._decode(self.params, self.caches, tokens,
-                                        pos_vec, active_mask)
-        out, firsts = [], []
-        for i in active:
-            r = self.slot_req[i]
-            r.generated.append(int(nxt[i]))
-            if len(r.generated) == 1:
-                firsts.append(r)
-            self.slot_pos[i] += 1
-            if r.done or self.slot_pos[i] >= self.max_len - 1:
-                self.finished.append(r)
-                out.append(r)
-                self.slot_req[i] = None
-                self.slot_pos[i] = 0
-        # one stamp for the whole step: tokens materialise at the step
-        # boundary (the simulator's model of it), not per slot
-        t_end = time.perf_counter()
-        for r in firsts:
-            r.t_first_token = t_end
-            self._trace(
-                {"type": "first_token", "rid": r.rid, "t": t_end})
-        for r in out:
-            r.t_finish = t_end
-            obs.metrics.counter("serving.finished")
-            self._trace(
-                {"type": "finish", "rid": r.rid, "t": t_end,
-                 "tokens": len(r.generated)})
-        self._trace({
-            "type": "step", "t": t_start, "dt": t_end - t_start,
-            "admitted": [r.rid for r in admitted], "active": len(active),
-            "queue_depth": len(self.queue)})
-        obs.metrics.counter("serving.steps")
-        obs.metrics.observe("serving.step_dt_s", t_end - t_start)
-        obs.add_span("serve.step", t_start, t_end,
-                     admitted=len(admitted), active=len(active),
-                     queue_depth=len(self.queue))
-        self.drift.observe(self.decision_step_s(), t_end - t_start,
-                           key=self._drift_machine_key())
-        return out
+        finished requests.
+
+        Each phase runs under a ``serve.*`` span (``repro.obs``), so that a
+        profiler trace can put the device's idle time down to one of them:
+        ``serve.admit``, ``serve.pack``, ``serve.decode``, ``serve.sync``
+        (waiting for the decoded tokens), ``serve.unpack`` and
+        ``serve.account``, all inside ``serve.step``."""
+        self.steps += 1
+        n = self.steps
+        with obs.span("serve.step", step=n) as sp:
+            t_start = time.perf_counter()
+            with obs.span("serve.admit", step=n):
+                admitted = self._admit()
+            active = [i for i, r in enumerate(self.slot_req)
+                      if r is not None]
+            # the queue changes no more in this step
+            sp.set(admitted=len(admitted), active=len(active),
+                   queue_depth=len(self.queue))
+            self._update_ladder(len(active))
+            if not active:
+                return []
+            with obs.span("serve.pack", step=n):
+                tokens = jnp.zeros((self.max_batch, 1), jnp.int32)
+                for i in active:
+                    r = self.slot_req[i]
+                    last = r.generated[-1] if r.generated else r.prompt[-1]
+                    tokens = tokens.at[i, 0].set(last)
+                # inactive slots decode harmlessly at position 0 (outputs
+                # ignored; admission overwrites their cache region)
+                pos_vec = jnp.minimum(jnp.array(self.slot_pos, jnp.int32),
+                                      self.max_len - 1)
+                active_mask = jnp.array([r is not None
+                                         for r in self.slot_req])
+            with obs.span("serve.decode", step=n):
+                nxt, self.caches = self._decode(self.params, self.caches,
+                                                tokens, pos_vec, active_mask)
+            with obs.span("serve.sync", step=n):
+                jax.block_until_ready(nxt)
+            with obs.span("serve.unpack", step=n):
+                out, firsts = [], []
+                for i in active:
+                    r = self.slot_req[i]
+                    r.generated.append(int(nxt[i]))
+                    if len(r.generated) == 1:
+                        firsts.append(r)
+                    self.slot_pos[i] += 1
+                    if r.done or self.slot_pos[i] >= self.max_len - 1:
+                        self.finished.append(r)
+                        out.append(r)
+                        self.slot_req[i] = None
+                        self.slot_pos[i] = 0
+            # one stamp for the whole step: tokens materialise at the step
+            # boundary (the simulator's model of it), not per slot
+            t_end = time.perf_counter()
+            with obs.span("serve.account", step=n):
+                for r in firsts:
+                    r.t_first_token = t_end
+                    self._trace(
+                        {"type": "first_token", "rid": r.rid, "t": t_end})
+                for r in out:
+                    r.t_finish = t_end
+                    obs.metrics.counter("serving.finished")
+                    self._trace(
+                        {"type": "finish", "rid": r.rid, "t": t_end,
+                         "tokens": len(r.generated)})
+                self._trace({
+                    "type": "step", "t": t_start, "dt": t_end - t_start,
+                    "admitted": [r.rid for r in admitted],
+                    "active": len(active), "queue_depth": len(self.queue)})
+                obs.metrics.counter("serving.steps")
+                self.drift.observe(self.decision_step_s(), t_end - t_start,
+                                   key=self._drift_machine_key())
+            return out
 
     def _drift_machine_key(self) -> str:
         """``name@geometry_fingerprint`` of the machine the frozen plans

@@ -1,20 +1,23 @@
 """Tests for ``repro.obs`` — tracing, metrics, drift, and explain().
 
-Covers the observability contract end to end without jax: span nesting
-and the disabled no-op fast path, the ``repro.obs/v1`` metrics snapshot
+Covers the observability contract end to end: span nesting, the
+disabled fast path (a bare profiler annotation) and spans on a profiler
+trace's host plane, the ``repro.obs/v1`` metrics snapshot
 round-trip and its agreement with the legacy plan-cache/sweep counters,
 DriftMonitor's ok → warn → stale transitions (including the simulator
 integration where a throttle fault flips the verdict), ``explain()``'s
 partition-of-total guarantee on every Table-2 cell, Chrome-trace export
 validity, and the ``python -m repro.obs`` CLI.
 """
+import glob
 import json
 import statistics
 
+import jax
 import pytest
 
 from repro import obs
-from repro.obs.trace import _NULL, Recorder, chrome_trace_from_serving
+from repro.obs.trace import Recorder, chrome_trace_from_serving
 from repro.obs.drift import DriftMonitor
 from repro.obs.metrics import MetricsRegistry
 
@@ -33,13 +36,43 @@ def _clean_obs():
 
 class TestSpans:
     def test_disabled_span_is_shared_noop(self):
+        # disabled, a span is a bare profiler annotation: nothing recorded
         assert not obs.enabled()
         s = obs.span("anything", attr=1)
-        assert s is _NULL
-        # the no-op supports the full call-site surface
+        assert isinstance(s, jax.profiler.TraceAnnotation)
+        # it supports the full call-site surface
         with s as inner:
             inner.set(more=2)
         assert obs.recorder.spans == []
+
+    @pytest.mark.parametrize("enabled", [False, True])
+    def test_span_reaches_profiler_session(self, tmp_path, enabled):
+        """Recorded or not, a span is on the trace's host plane, with its
+        attributes, those set while it was open too."""
+        if enabled:
+            obs.enable()
+        with jax.profiler.trace(str(tmp_path)):
+            with obs.span("obs.outer", step=3) as sp:
+                with obs.span("obs.inner", rid=7):
+                    pass
+                sp.set(active=2)
+        (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True)
+        found = {}
+        for plane in jax.profiler.ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("obs."):
+                        found[e.name] = (e.start_ns, e.duration_ns,
+                                         {str(k): str(v) for k, v in e.stats})
+        assert set(found) == {"obs.outer", "obs.inner"}
+        (o0, od, ostats), (i0, idur, istats) = (found["obs.outer"],
+                                                found["obs.inner"])
+        assert o0 <= i0 and i0 + idur <= o0 + od
+        assert ostats == {"step": "3", "active": "2"}
+        assert istats == {"rid": "7"}
+        assert [s.name for s in obs.recorder.spans] == (
+            ["obs.outer", "obs.inner"] if enabled else [])
 
     def test_disabled_add_span_records_nothing(self):
         assert obs.add_span("x", 0.0, 1.0) is None
@@ -87,11 +120,15 @@ class TestSpans:
 
     def test_overhead_disabled_vs_stubbed(self):
         # the hard <2% assert lives in benchmarks/bench_planner.py on the
-        # real Table-2 sweep; here just bound the per-call cost sanely
+        # real Table-2 sweep; here just bound the per-call cost sanely:
+        # open and close one span, its profiler annotation included
         import timeit
         n = 20000
-        disabled = timeit.timeit(
-            lambda: obs.span("hot", i=0), number=n) / n
+
+        def hot():
+            with obs.span("hot", i=0):
+                pass
+        disabled = timeit.timeit(hot, number=n) / n
         assert disabled < 5e-6  # single-digit microseconds at worst
 
 
