@@ -20,6 +20,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro import gemm as gemm_api
 from repro import obs
@@ -696,8 +697,11 @@ class ServingEngine:
         Each phase runs under a ``serve.*`` span (``repro.obs``), so that a
         profiler trace can put the device's idle time down to one of them:
         ``serve.admit``, ``serve.pack``, ``serve.decode``, ``serve.sync``
-        (waiting for the decoded tokens), ``serve.unpack`` and
-        ``serve.account``, all inside ``serve.step``."""
+        (waiting for the decoded tokens and copying them to the host),
+        ``serve.unpack`` and ``serve.account``, all inside ``serve.step``.
+        The decode's inputs cross to the device in one ``jax.device_put``
+        and its tokens come back in one ``jax.device_get``: no device work
+        per slot."""
         self.steps += 1
         n = self.steps
         with obs.span("serve.step", step=n) as sp:
@@ -713,22 +717,25 @@ class ServingEngine:
             if not active:
                 return []
             with obs.span("serve.pack", step=n):
-                tokens = jnp.zeros((self.max_batch, 1), jnp.int32)
+                # built on the host, then one transfer of all three
+                tokens = np.zeros((self.max_batch, 1), np.int32)
                 for i in active:
                     r = self.slot_req[i]
-                    last = r.generated[-1] if r.generated else r.prompt[-1]
-                    tokens = tokens.at[i, 0].set(last)
+                    tokens[i, 0] = r.generated[-1] if r.generated \
+                        else r.prompt[-1]
                 # inactive slots decode harmlessly at position 0 (outputs
                 # ignored; admission overwrites their cache region)
-                pos_vec = jnp.minimum(jnp.array(self.slot_pos, jnp.int32),
-                                      self.max_len - 1)
-                active_mask = jnp.array([r is not None
-                                         for r in self.slot_req])
+                pos_vec = np.minimum(self.slot_pos,
+                                     self.max_len - 1).astype(np.int32)
+                active_mask = np.array([r is not None
+                                        for r in self.slot_req], bool)
+                tokens, pos_vec, active_mask = jax.device_put(
+                    (tokens, pos_vec, active_mask))
             with obs.span("serve.decode", step=n):
                 nxt, self.caches = self._decode(self.params, self.caches,
                                                 tokens, pos_vec, active_mask)
             with obs.span("serve.sync", step=n):
-                jax.block_until_ready(nxt)
+                nxt = jax.device_get(nxt)    # waits for the decode
             with obs.span("serve.unpack", step=n):
                 out, firsts = [], []
                 for i in active:
