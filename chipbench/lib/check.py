@@ -2,8 +2,9 @@
 
 Once the window has closed, a sample of the finished requests, drawn from
 the seed and holding the one with the most served tokens, goes through the
-plain reference (``lib.reference``): one full-sequence float32 forward over
-each prompt and its served tokens.  For every served token the reference
+plain reference (the module the configuration names, ``lib.reference`` by
+default): one full-sequence float32 forward over each prompt and its
+served tokens.  For every served token the reference
 gives the gap by which that token's logit lies below its best logit at that
 position; greedy decoding that agrees with the reference to rounding keeps
 the gap small.  A configuration's file names the numbers compared and
@@ -61,8 +62,7 @@ def gaps(weights: dict, spec, requests: list, *, control: bool = False,
     served token, or with ``control`` of the control's first choice) at
     each served position.  ``out_rows`` pads the positions read, so every
     request runs one compiled shape per padded length."""
-    from lib.reference import logits_at
-
+    logits_at = spec.reference.logits_at
     out = []
     for s in requests:
         prompt = list(s.engine_req.prompt)

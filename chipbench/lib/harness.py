@@ -109,7 +109,8 @@ def build(cell, seed: int) -> Setup:
                             jax.random.key(0))
     weights = make_weights(cell.model, shapes["embed"]["table"].shape[0],
                            seed)
-    engine = ServingEngine(lm, program_tree(weights, shapes),
+    tree = program_tree(weights, shapes, cell.model.reference.PROGRAM_LEAF)
+    engine = ServingEngine(lm, tree,
                            max_batch=cell.engine["max_batch"],
                            max_len=cell.engine["max_len"])
     return Setup(cell=cell, weights=weights, engine=engine,

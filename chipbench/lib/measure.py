@@ -10,7 +10,6 @@ read (the metric is then left out of the result).
 from __future__ import annotations
 
 import dataclasses
-import importlib.util
 from pathlib import Path
 
 from lib.stats import percentile
@@ -80,9 +79,6 @@ END_TO_END = {f.__name__: f for f in (ttft_p75_s, itl_p95_ms,
 
 def reader(metrics_dir: Path, name: str):
     """``read`` of ``metrics_dir/<name>.py``."""
-    path = metrics_dir / f"{name}.py"
-    mod_spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    from lib.spec import load_module
+
+    return load_module(metrics_dir / f"{name}.py").read

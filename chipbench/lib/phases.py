@@ -16,10 +16,12 @@ bsd,dhe->bshe/dot_general``), kept with the operation's metadata, where
 ``reduce_phases`` puts the device's idle time in the window down to the
 innermost program span open over it (the span's self time), and each
 program's device time down to its operations' scopes.  ``lib.trace``
-reduces the same trace for the benchmark's metrics; nothing here changes
-what it reads.  ``export`` keeps what ``reduce_phases`` reads in a small
-gzipped file that tests can reduce; ``phases.py`` beside ``run.py`` runs a
-traced window and prints the reduction.
+reads a profile through ``read_xplane`` too, reduces the same events for
+the benchmark's metrics, and keeps this reduction beside its own
+(``Reduction.phases``), where the readers of the program's phases find it.
+``export`` keeps what both reduce in a small gzipped file that tests can
+reduce; ``phases.py`` beside ``run.py`` runs a traced window and prints
+the reduction.
 """
 from __future__ import annotations
 
@@ -123,8 +125,9 @@ def _xspace_type():
 
 def read_xplane(path: str) -> Events:
     """The events of the ``.xplane.pb`` at ``path`` that ``reduce_phases``
-    reads.  Times as ``jax.profiler.ProfileData`` gives them: the line's
-    ns plus whole ns of offset."""
+    and ``lib.trace.reduce_planes`` read.  Times as
+    ``jax.profiler.ProfileData`` gives them: the line's ns plus whole ns
+    of offset."""
     with open(path, "rb") as fh:
         space = _xspace_type().FromString(fh.read())
     win, host, devices = None, [], {}
@@ -362,7 +365,11 @@ def export(ev: Events, out: str) -> None:
 
 def load_export(path: str) -> Events:
     with gzip.open(path, "rt") as fh:
-        doc = json.load(fh)
+        return events_of(json.load(fh))
+
+
+def events_of(doc: dict) -> Events:
+    """The events of an ``export`` file's document."""
     return Events(
         window=tuple(doc["window"]) if doc["window"] else None,
         host=_rows(doc["host"]),

@@ -17,9 +17,23 @@ otherwise computed in bf16 passes.
 ``quantize`` gives the control: the same forward with every weight matrix
 rounded to float8 (e4m3, one scale per tensor and layer), the precision
 below the bf16 that the configurations serve in.
+
+A configuration runs against the reference module its file names
+(``"reference": "<module>"``, ``lib/<module>.py``; this one by default).
+Such a module owns what is particular to the block it runs:
+
+- ``logits_at(weights, spec, tokens, rows, quant=)``, the forward;
+- its weight table: ``shapes(spec, vocab_rows)``, ``init(spec, name,
+  shape)`` (the mean and the standard deviation of each weight's normal
+  draw) and ``PROGRAM_LEAF``, the program's parameter of each weight;
+- ``PROGRAM_FIELD``, the published keys it runs beyond ``lib.spec``'s,
+  each with the program field that runs it;
+- ``program_config(spec, base, sets)``, the program's configuration with
+  ``sets`` applied, refusing a block that the module cannot run.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -27,6 +41,86 @@ import jax.numpy as jnp
 
 HI = jax.lax.Precision.HIGHEST
 FP8_MAX = 448.0
+
+#: no published keys beyond ``lib.spec.PROGRAM_FIELD``
+PROGRAM_FIELD = {}
+#: (parent key, leaf key) of a program parameter -> the weight's name here
+PROGRAM_LEAF = {
+    ("embed", "table"): "embed",
+    ("final_norm", "scale"): "final_norm",
+    ("norm1", "scale"): "ln1",
+    ("norm2", "scale"): "ln2",
+    ("attn", "wq"): "wq", ("attn", "wk"): "wk", ("attn", "wv"): "wv",
+    ("attn", "wo"): "wo", ("attn", "bq"): "bq", ("attn", "bk"): "bk",
+    ("attn", "bv"): "bv",
+    ("mlp", "w_gate"): "mlp_gate", ("mlp", "w_up"): "mlp_up",
+    ("mlp", "w_down"): "mlp_down",
+    ("moe", "router"): "router", ("moe", "w_gate"): "expert_gate",
+    ("moe", "w_up"): "expert_up", ("moe", "w_down"): "expert_down",
+}
+
+
+def program_config(spec, base, sets: dict):
+    """``base`` (the program's registered configuration) with ``sets`` and
+    a uniform block pattern at the file's depth; refuses what this module
+    does not run: a mixed block pattern, or a block that is not the
+    RMSNorm, SiLU-gated, text-only one."""
+    kind = base.block_pattern[0]
+    if set(base.block_pattern) != {kind}:
+        raise ValueError(f"{spec.name}: only a uniform block pattern "
+                         f"can be run at another depth")
+    cfg = dataclasses.replace(
+        base, block_pattern=(kind,) * sets["n_layers"], **sets)
+    if (spec.data.get("hidden_act", "silu") != "silu"
+            or cfg.act != "swiglu" or cfg.norm_type != "rmsnorm"
+            or cfg.logit_softcap or cfg.frontend != "none"):
+        raise ValueError(f"{spec.name}: the reference runs the "
+                         f"RMSNorm, SiLU-gated, text-only block only")
+    return cfg
+
+
+def shapes(spec, vocab_rows: int) -> dict[str, tuple]:
+    """The weight shapes for ``spec``; the embedding holds ``vocab_rows >=
+    vocab_size`` rows (the rows past the vocabulary are never looked up
+    and never ranked)."""
+    L, d = spec.num_hidden_layers, spec.hidden_size
+    h, hkv, hd = (spec.num_attention_heads, spec.num_key_value_heads,
+                  spec.head_dim)
+    f = spec.intermediate_size
+    out = {"embed": (vocab_rows, d), "final_norm": (d,),
+           "ln1": (L, d), "ln2": (L, d),
+           "wq": (L, d, h, hd), "wk": (L, d, hkv, hd), "wv": (L, d, hkv, hd),
+           "wo": (L, h, hd, d)}
+    if spec.data.get("attention_bias"):
+        out.update(bq=(L, h, hd), bk=(L, hkv, hd), bv=(L, hkv, hd))
+    if spec.is_moe:
+        e = spec.num_local_experts
+        out.update(router=(L, d, e), expert_gate=(L, e, d, f),
+                   expert_up=(L, e, d, f), expert_down=(L, e, f, d))
+    else:
+        out.update(mlp_gate=(L, d, f), mlp_up=(L, d, f), mlp_down=(L, f, d))
+    return out
+
+
+def init(spec, name: str, shape: tuple) -> tuple[float, float]:
+    """(mean, standard deviation) of the weight ``name``'s normal draw.
+    Norm scales about 1; fan-in scaled matrices; the embedding at the
+    published ``initializer_range``: with the head tied, an embedding of
+    unit scale makes every position's top logit its own input token, and
+    greedy decoding then repeats it whatever the arithmetic."""
+    if name in ("ln1", "ln2", "final_norm"):
+        return 1.0, 0.1
+    if name == "embed":
+        return 0.0, spec.initializer_range
+    if name in ("bq", "bk", "bv"):
+        return 0.0, 0.1
+    if name in ("wq", "wk", "wv"):           # (L, d, heads, head_dim)
+        fan_in = shape[-3]
+    elif name == "wo":                       # (L, heads, head_dim, d)
+        fan_in = shape[-3] * shape[-2]
+    else:                                    # (..., fan_in, fan_out)
+        fan_in = shape[-2]
+    return 0.0, fan_in ** -0.5
 
 
 def fp8_round(w):

@@ -4,14 +4,19 @@ A cell (``workloads`` entry) names a configuration and a traffic mix.  The
 configuration's file is the one its ``configs`` entry names; the mix is
 ``traffic/<traffic>.<config>.json`` when the cell has a mix of its own, else
 ``traffic/<traffic>.json``; a per-layer metric's reader is
-``metrics/<metric>.py``.  Adding a cell, a mix, a configuration or a metric
-is adding files and entries: nothing here names one.
+``metrics/<metric>.py``; a configuration's plain reference is the module
+``lib/<reference>.py`` that its file names (``lib/reference.py`` where it
+names none).  Adding a cell, a mix, a configuration, its reference or a
+metric is adding files and entries: nothing here names one.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
+import importlib.util
 import json
 import re
+import sys
 from pathlib import Path
 
 from lib.traffic import Mix
@@ -46,14 +51,38 @@ PROGRAM_FIELD = {
 #: every one as the file states it
 PLAIN = {"embedding_multiplier": 1.0, "residual_multiplier": 1.0,
          "logits_scaling": 1.0}
+#: published keys that ``program_config`` runs itself
+OWN_KEYS = {"intermediate_size", "capacity_factor", "attention_multiplier",
+            *PLAIN}
+#: keys that describe a configuration and set no program field
+DESCRIPTIVE = {"source", "paper", "reduced", "assumed", "deployment",
+               "engine", "check", "reference", "published",
+               "initializer_range", "hidden_act", "torch_dtype",
+               "program_arch"}
+MODULE = re.compile(r"^[A-Za-z_][A-Za-z0-9_]{0,63}$")
+
+
+@functools.lru_cache(maxsize=None)
+def load_module(path: Path):
+    """The Python module in the file ``path``, loaded once per process."""
+    if not path.is_file():
+        raise FileNotFoundError(f"no module {path}")
+    name = f"chipbench_{path.parent.name}_{path.stem}".replace(".", "_")
+    mod_spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    sys.modules[name] = mod          # where a dataclass looks up its module
+    mod_spec.loader.exec_module(mod)
+    return mod
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
 class ModelSpec:
-    """A configuration as it is run (its file's keys)."""
+    """A configuration as it is run (its file's keys); ``lib_dir`` holds
+    the reference modules it may name."""
 
     name: str
     data: dict
+    lib_dir: Path = BENCH_DIR / "lib"
 
     def __getattr__(self, key):
         try:
@@ -74,42 +103,51 @@ class ModelSpec:
             return self.attention_multiplier
         return float(self.data.get(key, PLAIN[key]))
 
+    @property
+    def reference(self):
+        """The plain reference this configuration runs against: the module
+        ``lib/<reference>.py`` that its file names, ``reference`` where it
+        names none (see that module for what one holds)."""
+        name = self.data.get("reference", "reference")
+        if not isinstance(name, str) or not MODULE.match(name):
+            raise ValueError(f"{self.name}: reference {name!r} is not a "
+                             f"module name")
+        return load_module(self.lib_dir / f"{name}.py")
+
     def program_config(self):
         """The program's ``ModelConfig``: its registered architecture
-        (block kind, activation, norm) with every size of this file."""
+        (block kind, activation, norm) with every size of this file.
+        Refuses a published key that nothing here or in the reference
+        runs, a multiplier the program cannot run, and a block the
+        reference does not run."""
         from repro.configs import get_config
 
+        ref = self.reference
+        fields = {**PROGRAM_FIELD, **ref.PROGRAM_FIELD}
+        unknown = sorted(set(self.data) - set(fields) - OWN_KEYS
+                         - DESCRIPTIVE)
+        if unknown:
+            raise ValueError(f"{self.name}: no program field runs the "
+                             f"published keys {unknown}")
         base = get_config(self.data["program_arch"])
-        sets = {PROGRAM_FIELD[k]: v for k, v in self.data.items()
-                if k in PROGRAM_FIELD}
+        sets = {fields[k]: v for k, v in self.data.items() if k in fields}
         if self.is_moe:
             sets.update(moe_d_ff=self.intermediate_size, d_ff=0)
         else:
             sets["d_ff"] = self.intermediate_size
         if "capacity_factor" in self.data:
             sets["capacity_factor"] = self.data["capacity_factor"]
-        kind = base.block_pattern[0]
-        if set(base.block_pattern) != {kind}:
-            raise ValueError(f"{self.name}: only a uniform block pattern "
-                             f"can be run at another depth")
-        sets["block_pattern"] = (kind,) * sets["n_layers"]
         # a multiplier goes to the program's field of the same name; where
         # the program has none, only the plain block's value can run
-        fields = {f.name for f in dataclasses.fields(base)}
+        program = {f.name for f in dataclasses.fields(base)}
         plain = dict(PLAIN, attention_multiplier=self.head_dim ** -0.5)
         for key, value in plain.items():
-            if key in fields:
+            if key in program:
                 sets[key] = self.multiplier(key)
             elif abs(self.multiplier(key) - value) > 1e-12:
                 raise ValueError(f"{self.name}: the program has no "
                                  f"{key} to run {self.multiplier(key)}")
-        cfg = dataclasses.replace(base, **sets)
-        if (self.data.get("hidden_act", "silu") != "silu"
-                or cfg.act != "swiglu" or cfg.norm_type != "rmsnorm"
-                or cfg.logit_softcap or cfg.frontend != "none"):
-            raise ValueError(f"{self.name}: the reference runs the "
-                             f"RMSNorm, SiLU-gated, text-only block only")
-        return cfg
+        return ref.program_config(self, base, sets)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -150,7 +188,8 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     mix = Mix.from_dict(w["traffic"],
                         _read_json(mix_path(bench_dir, w["traffic"],
                                             w["config"])))
-    return Cell(name=name, model=ModelSpec(w["config"], data), mix=mix,
+    model = ModelSpec(w["config"], data, lib_dir=bench_dir / "lib")
+    return Cell(name=name, model=model, mix=mix,
                 chips=int(w["chips"]), engine=dict(data["engine"]))
 
 
@@ -178,6 +217,10 @@ def validate(bench: dict, root: Path = ROOT) -> list[str]:
             name_ok("reduced key", key)
         if not (root / c["file"]).is_file():
             faults.append(f"config {c['name']}: no file {c['file']}")
+            continue
+        ref = _read_json(root / c["file"]).get("reference", "reference")
+        if not (root / bench["paths"][0] / "lib" / f"{ref}.py").is_file():
+            faults.append(f"config {c['name']}: no reference lib/{ref}.py")
     cells = set()
     for w in bench["workloads"]:
         name_ok("workload", w["name"])

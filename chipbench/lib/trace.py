@@ -8,11 +8,18 @@ line holds one event per operation executed and ``XLA Modules`` one per
 jitted program run.  The harness's host annotations (``lib.serve``) are
 events of the host plane; the one named ``window`` spans the measured
 window, and only device time inside it counts.
+
+A reduction of a profile (``reduce_file``) or of an export that keeps the
+program's spans and scopes (``reduce_export``) also holds, as ``phases``,
+``lib.phases``' reduction of the same trace by those spans and scopes, for
+the readers that read the program's own phases.
 """
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import glob
+import itertools
 import os
 import re
 from collections import defaultdict
@@ -54,6 +61,8 @@ class Reduction:
     ops: dict                            # name -> [count, seconds]
     idle_by_label: dict                  # host label -> seconds
     devices: int
+    phases: object = None                # lib.phases.Phases, where the
+    #                                      trace has the program's spans
 
     def module(self, pattern: str) -> tuple[int, float]:
         """(count, seconds) of the modules whose name matches ``pattern``
@@ -79,12 +88,17 @@ class Reduction:
 
     def breakdown(self, top: int = 10) -> dict:
         """The device operations that took most time (the loops that hold
-        other operations left out) and the idle time by host annotation."""
+        other operations left out) and the idle time by host annotation,
+        then by the innermost program span (``Phases.idle_rows``), at most
+        ``top`` of each."""
         ops = sorted(((short_name(k), v[1]) for k, v in self.ops.items()
                       if _opcode(k) != "while"), key=lambda kv: -kv[1])
-        gaps = sorted(self.idle_by_label.items(), key=lambda kv: -kv[1])
+        gaps = [[k, v] for k, v in sorted(self.idle_by_label.items(),
+                                          key=lambda kv: -kv[1])]
+        if self.phases is not None:
+            gaps += self.phases.idle_rows()
         return {"device_ops": [[k, v] for k, v in ops[:top]],
-                "idle_gaps": [[k, v] for k, v in gaps[:top]]}
+                "idle_gaps": gaps[:top]}
 
 
 def find_xplane(log_dir: str) -> str:
@@ -140,6 +154,8 @@ def reduce_planes(planes) -> Reduction:
     _, lo, hi = windows[0]
     labels = sorted(((s, e, n) for n, s, e in host_events if n != WINDOW),
                     key=lambda t: t[0])
+    # reach[i]: the latest end among labels[:i + 1]
+    reach = list(itertools.accumulate((e for _, e, _ in labels), max))
     modules = defaultdict(lambda: [0, 0.0])
     ops = defaultdict(lambda: [0, 0.0])
     idle = defaultdict(float)
@@ -165,7 +181,7 @@ def reduce_planes(planes) -> Reduction:
         edges = [lo] + [x for iv in merged for x in iv] + [hi]
         for gs, ge in zip(edges[::2], edges[1::2]):
             if ge > gs:
-                _label_gap(gs, ge, labels, idle)
+                _label_gap(gs, ge, labels, reach, idle)
     nd = len(devices)
     return Reduction(window_s=(hi - lo) * 1e-9, busy_s=busy_total / nd,
                      modules=dict(modules), ops=dict(ops),
@@ -173,11 +189,14 @@ def reduce_planes(planes) -> Reduction:
                      devices=nd)
 
 
-def _label_gap(gs, ge, labels, idle):
+def _label_gap(gs, ge, labels, reach, idle):
     """Split the idle gap [gs, ge) over the host annotations it overlaps;
-    what no annotation covers is the harness's own (``other``)."""
+    what no annotation covers is the harness's own (``other``).  The scan
+    starts at the first annotation whose ``reach`` passes ``gs``: every
+    one before it ends by the gap's start."""
     covered = 0
-    for s, e, name in labels:
+    for i in range(bisect.bisect_right(reach, gs), len(labels)):
+        s, e, name = labels[i]
         if s >= ge:
             break
         a, b = _clip(s, e, gs, ge)
@@ -190,9 +209,23 @@ def _label_gap(gs, ge, labels, idle):
         idle["other"] += rest * 1e-9
 
 
+def reduce_events(ev) -> Reduction:
+    """The reduction of ``ev`` (``lib.phases.Events``: a profile as
+    ``read_xplane`` reads it, or an export), with ``phases``."""
+    from lib.phases import reduce_phases
+
+    red = reduce_planes(ev.planes())
+    red.phases = reduce_phases(ev)
+    return red
+
+
 def reduce_file(path: str) -> Reduction:
-    from jax.profiler import ProfileData
-    return reduce_planes(ProfileData.from_file(path).planes)
+    """The reduction of the ``.xplane.pb`` at ``path``, with ``phases``.
+    The file is read once, by ``lib.phases.read_xplane``, whose events
+    reduce as ``jax.profiler.ProfileData``'s do."""
+    from lib.phases import read_xplane
+
+    return reduce_events(read_xplane(path))
 
 
 def describe(path: str, top: int = 40) -> dict:
@@ -223,41 +256,48 @@ def describe(path: str, top: int = 40) -> dict:
 
 
 def export(path: str, out: str) -> None:
-    """Write the events ``reduce_planes`` reads (device ``XLA Ops`` and
-    ``XLA Modules``, the host annotations) of the trace at ``path`` to a
+    """Write what ``reduce_file`` reads of the trace at ``path`` (the
+    device operations with their programs and scopes, the harness's
+    annotations and the program's spans: ``lib.phases.export``) to a
     gzipped JSON file: a small trace that tests can reduce."""
+    from lib import phases
+    phases.export(phases.read_xplane(path), out)
+
+
+def _read_export(path: str) -> dict:
     import gzip
     import json
 
-    from jax.profiler import ProfileData
-    planes = []
-    for p in ProfileData.from_file(path).planes:
-        device = re.fullmatch(r"/device:TPU:\d+", p.name)
-        if not (device or p.name.startswith("/host:")):
-            continue
-        lines = []
-        for line in p.lines:
-            if device and line.name not in ("XLA Ops", "XLA Modules"):
-                continue
-            evs = [[e.name, e.start_ns, e.duration_ns] for e in line.events
-                   if device or e.name in HOST_LABELS or e.name == WINDOW]
-            if evs:
-                lines.append({"name": line.name, "events": evs})
-        planes.append({"name": p.name, "lines": lines})
-    with gzip.open(out, "wt") as f:
-        json.dump({"planes": planes}, f)
+    with gzip.open(path, "rt") as f:
+        return json.load(f)
+
+
+def _first_form(doc: dict) -> list:
+    """The planes of an export written before exports kept the program's
+    spans and scopes (``{"planes": [...]}``)."""
+    from types import SimpleNamespace as NS
+
+    return [NS(name=p["name"], lines=[
+        NS(name=line["name"], events=[
+            NS(name=n, start_ns=s, duration_ns=d) for n, s, d in line["events"]])
+        for line in p["lines"]]) for p in doc["planes"]]
 
 
 def load_export(path: str) -> list:
     """The planes of an ``export`` file, shaped as ``reduce_planes`` reads
     them."""
-    import gzip
-    import json
-    from types import SimpleNamespace as NS
+    from lib.phases import events_of
 
-    with gzip.open(path, "rt") as f:
-        data = json.load(f)
-    return [NS(name=p["name"], lines=[
-        NS(name=line["name"], events=[
-            NS(name=n, start_ns=s, duration_ns=d) for n, s, d in line["events"]])
-        for line in p["lines"]]) for p in data["planes"]]
+    doc = _read_export(path)
+    return _first_form(doc) if "planes" in doc else events_of(doc).planes()
+
+
+def reduce_export(path: str) -> Reduction:
+    """The reduction of an ``export`` file; ``phases`` is None for the
+    first form, which has no spans or scopes."""
+    from lib.phases import events_of
+
+    doc = _read_export(path)
+    if "planes" in doc:
+        return reduce_planes(_first_form(doc))
+    return reduce_events(events_of(doc))

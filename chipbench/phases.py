@@ -11,9 +11,10 @@ the per-layer metrics and breakdown that ``run.py`` reads from it
 (``lib.trace``), and ``lib.phases``' reduction of the same trace: device
 idle time by innermost program span (``idle_gaps``, beside the harness's
 rows), the per-step numbers, the spans, and the device time per decode run
-of each scope of the decode program.  ``--export`` writes what
-``lib.phases`` read to ``FILE`` (gzipped JSON, small enough to keep as a
-test's data for a window of a few seconds).  The second form prints the
+of each scope of the decode program, and the seconds the trace took to
+read.  ``--export`` writes what the reductions read to ``FILE``
+(``lib.trace.export``: gzipped JSON, small enough to keep as a test's data
+for a window of a few seconds).  The second form prints the
 reduction of a kept profile (``calibrate.py trace --out``) or of such a
 file.
 """
@@ -31,16 +32,15 @@ HERE = Path(__file__).resolve().parent
 sys.path.insert(0, str(HERE))
 
 import run as bench_run  # noqa: E402
-from lib.phases import (DECODE, export, load_export, read_xplane,  # noqa
-                        reduce_phases)
-from lib.trace import find_xplane, reduce_planes  # noqa: E402
+from lib.phases import DECODE  # noqa: E402
+from lib.trace import (export, find_xplane, reduce_export,  # noqa: E402
+                       reduce_file)
 
 
-def summary(events) -> dict:
-    """``lib.trace``'s idle rows and ``lib.phases``' reduction of
-    ``events``, side by side."""
-    ph = reduce_phases(events)
-    red = reduce_planes(events.planes())
+def summary(red) -> dict:
+    """``lib.trace``'s idle rows and ``lib.phases``' reduction
+    (``red.phases``) of one trace, side by side."""
+    ph = red.phases
     runs = sum(c for m, (c, _) in ph.modules.items() if DECODE in m)
     scopes = {}
     for m, by in ph.scopes.items():
@@ -49,7 +49,7 @@ def summary(events) -> dict:
                 scopes[sc] = scopes.get(sc, 0.0) + 1e3 * sec / runs
     return {"window_s": ph.window_s, "busy_s": ph.busy_s,
             "per_step": ph.per_step(),
-            "idle_gaps": red.breakdown()["idle_gaps"] + ph.idle_rows(),
+            "idle_gaps": red.breakdown(top=64)["idle_gaps"],
             "spans": ph.spans,
             "decode_ms_by_scope": sorted(([k, v] for k, v in scopes.items()),
                                          key=lambda kv: -kv[1]),
@@ -59,7 +59,6 @@ def summary(events) -> dict:
 def traced_run(args) -> dict:
     from lib import harness, measure
     from lib.spec import load_benchmark, load_cell, metric_entries
-    from lib.trace import reduce_file
 
     bench = load_benchmark(bench_run.ROOT)
     cell = load_cell(args.workload, bench_run.ROOT)
@@ -72,12 +71,13 @@ def traced_run(args) -> dict:
                                trace_dir=trace_dir, peaks=peaks,
                                t_start=T_START)
         path = find_xplane(trace_dir)
+        t0 = time.perf_counter()
         out.run.trace = reduce_file(path)
-        events = read_xplane(path)
+        read_s = time.perf_counter() - t0
+        if args.export:
+            export(path, args.export)
     finally:
         shutil.rmtree(trace_dir, ignore_errors=True)
-    if args.export:
-        export(events, args.export)
     run = out.run
     return {"seed": args.seed, "correct_gap": out.gap,
             "compiles_in_window": out.compiles_in_window,
@@ -86,7 +86,8 @@ def traced_run(args) -> dict:
                 HERE / "metrics", m["name"])(run)
                 for m in metric_entries(bench, cell.name, True)},
             "breakdown": run.trace.breakdown(),
-            "phases": summary(events)}
+            "trace_read_s": read_s,
+            "phases": summary(run.trace)}
 
 
 def main(argv=None) -> None:
@@ -99,9 +100,9 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     if args.read:
         src = Path(args.read)
-        events = (read_xplane(find_xplane(str(src))) if src.is_dir()
-                  else load_export(str(src)))
-        print(json.dumps(summary(events)), flush=True)
+        red = (reduce_file(find_xplane(str(src))) if src.is_dir()
+               else reduce_export(str(src)))
+        print(json.dumps(summary(red)), flush=True)
         return
     if None in (args.workload, args.seed, args.seconds):
         ap.error("--workload, --seed and --seconds, or --read")

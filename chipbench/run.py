@@ -134,10 +134,12 @@ def main(argv=None) -> None:
     trace = None
     if trace_dir is not None:
         from lib.trace import find_xplane, reduce_file
+        t0 = time.perf_counter()
         try:
             trace = out.run.trace = reduce_file(find_xplane(trace_dir))
         finally:
             shutil.rmtree(trace_dir, ignore_errors=True)
+        note(f"trace read in {time.perf_counter() - t0:.2f} s")
     from lib import check, measure
     lag = measure.reader(HERE / "metrics", "gen.lag_p95_ms")(out.run)
     both = check.evaluate(out.gaps, {k: None for k in check.NUMBERS})

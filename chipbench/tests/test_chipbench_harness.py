@@ -8,6 +8,7 @@ widest gap over 14 seeds read at most 0.0080, the control's at least
 0.0325; the limit sits between them.
 """
 import json
+import shutil
 import sys
 from pathlib import Path
 
@@ -22,6 +23,10 @@ from lib.peaks import PEAKS  # noqa: E402
 from lib.spec import load_cell  # noqa: E402
 
 TINY_LIMIT = 0.02
+#: the per-layer metrics read from the program's spans and scopes
+PHASE_METRICS = ("engine.step_idle_ms", "engine.programs_per_step",
+                 "model.decode_attention_share",
+                 "model.decode_weight_cast_ms")
 TINY = {"program_arch": "qwen2-1.5b", "hidden_size": 64,
         "intermediate_size": 128, "num_attention_heads": 4,
         "num_key_value_heads": 2, "head_dim": 16, "num_hidden_layers": 2,
@@ -50,8 +55,8 @@ MIX = {"arrival": "poisson", "rate": 20.0, "preroll_s": 0.5,
 @pytest.fixture(scope="module")
 def root(tmp_path_factory):
     root = tmp_path_factory.mktemp("checkout")
-    (root / "chipbench" / "configs").mkdir(parents=True)
-    (root / "chipbench" / "traffic").mkdir()
+    shutil.copytree(BENCH, root / "chipbench",
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
     configs, cells = [], []
     for name, data in (("tiny", TINY), ("tiny-moe", TINY_MOE)):
         (root / "chipbench" / "configs" / f"{name}.json").write_text(
@@ -162,6 +167,7 @@ def test_result_line(root):
     from types import SimpleNamespace
 
     import run as bench_run
+    from lib.phases import Phases
     from lib.trace import Reduction
 
     out = _run(root)
@@ -196,9 +202,111 @@ def test_result_line(root):
     assert got["device.idle_share.chat"]["value"] == pytest.approx(50.0)
     assert 0 < got["gemm_roofline"]["value"]
     assert "grouped_gemm_roofline" not in got
+    # a trace with no program spans leaves their readers silent
+    assert not set(PHASE_METRICS) & set(got)
+    assert res["breakdown"]["idle_gaps"] == [["step", 0.2]]
+    red.phases = Phases(
+        window_s=red.window_s, busy_s=red.busy_s,
+        spans={"serve.step": [10, 0.5], "serve.decode": [10, 0.01]},
+        idle_self={"serve.sync": 0.02}, idle_in={"serve.step": 0.03},
+        modules={"jit__decode_impl": [10, 0.1], "jit_fn": [3, 0.05]},
+        scopes={"jit__decode_impl": {"attention": [10, 0.01],
+                                     "cast_weights": [10, 0.04],
+                                     "": [5, 0.05]}})
+    res = bench_run.result(cell, bench, out, [dev], red, checks)
+    got = {k: res["metrics"][k]["value"] for k in PHASE_METRICS}
+    assert got == pytest.approx(dict(zip(PHASE_METRICS,
+                                         (3.0, 1.3, 10.0, 4.0))))
+    assert res["breakdown"]["idle_gaps"] == [["step", 0.2],
+                                             ["serve.sync", 0.02]]
     res = bench_run.result(cell, bench, out, [dev], None,
                            {"logit_gap": (None, TINY_LIMIT)})
     assert res["correct"] is False
+
+
+def _digest(weights) -> str:
+    import hashlib
+
+    import numpy as np
+
+    h = hashlib.sha256()
+    for name in sorted(weights):
+        h.update(name.encode())
+        h.update(np.asarray(weights[name]).tobytes())
+    return h.hexdigest()
+
+
+@pytest.mark.parametrize("data, digest", [
+    (TINY, "12873ee67856ec8b5de2165316c441c8c1202898aaa65f6ed1a0399fefa93287"),
+    (TINY_MOE,
+     "005c6c08c9acaf0fd958746f75e75c2230e347679fc951f7615377d927ac1cf7")])
+def test_weights_from_the_seed_are_pinned(data, digest):
+    """The weights of a seed past 32 bits, bit for bit as the benchmark
+    made them before the reference module owned their table."""
+    from lib.spec import ModelSpec
+    from lib.weights import make_weights
+
+    w = make_weights(ModelSpec("t", data), 4096, 2**33 + 7)
+    assert _digest(w) == digest
+
+
+def test_a_reference_added_as_files_only(tmp_path):
+    """A configuration that names a reference module of its own (here a
+    copy of ``lib/reference.py`` under another name), added as new files
+    and entries beside the checkout's, with no file under ``lib/``
+    changed: the harness runs it end to end, correct, and its gaps are
+    those of the default reference on the same served tokens."""
+    from lib.weights import make_weights
+
+    root = tmp_path / "checkout"
+    shutil.copytree(BENCH, root / "chipbench",
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    lib = root / "chipbench" / "lib"
+    before = {p.name: p.read_bytes() for p in lib.glob("*.py")}
+    # the copy counts its calls, so that a run that used another shows
+    (lib / "reference_copy.py").write_bytes(before["reference.py"] + b"""
+CALLS = []
+_logits_at, _shapes = logits_at, shapes
+
+
+def logits_at(*args, **kwargs):
+    CALLS.append("logits_at")
+    return _logits_at(*args, **kwargs)
+
+
+def shapes(*args):
+    CALLS.append("shapes")
+    return _shapes(*args)
+""")
+    configs, cells = [], []
+    for name, data in (("tiny", TINY),
+                       ("tiny-copy", dict(TINY, reference="reference_copy"))):
+        (root / "chipbench" / "configs" / f"{name}.json").write_text(
+            json.dumps(data))
+        configs.append({"name": name, "source": "test",
+                        "file": f"chipbench/configs/{name}.json",
+                        "reduced": [], "why": "test"})
+        cells.append({"name": f"{name}.chat", "config": name,
+                      "traffic": "chat", "chips": 1, "why": "test"})
+    (root / "chipbench" / "traffic" / "chat.json").write_text(json.dumps(MIX))
+    (root / "BENCHMARK.json").write_text(json.dumps({
+        "paths": ["chipbench"], "configs": configs, "workloads": cells,
+        "end_to_end": [], "per_layer": []}))
+    copy = load_cell("tiny-copy.chat", root)
+    assert copy.model.reference.__file__ == str(lib / "reference_copy.py")
+    out = _run(root, "tiny-copy.chat")
+    assert out.compiles_in_window == 0 and out.sampled_tokens >= 40
+    assert _correct(root, out, "tiny-copy.chat")
+    assert {"logits_at", "shapes"} <= set(copy.model.reference.CALLS)
+    plain = load_cell("tiny.chat", root)
+    w = make_weights(copy.model, 4096, 3)
+    assert _digest(w) == _digest(make_weights(plain.model, 4096, 3))
+    mine, _, _ = harness.outputs(w, copy, out.run.record, 3, False)
+    theirs, _, _ = harness.outputs(w, plain, out.run.record, 3, False)
+    for a, b, c in zip(out.gaps, mine, theirs, strict=True):
+        assert (a == b).all() and (b == c).all()
+    assert {p.name: p.read_bytes() for p in lib.glob("*.py")
+            if p.name != "reference_copy.py"} == before
 
 
 @pytest.mark.parametrize("lengths, size", [

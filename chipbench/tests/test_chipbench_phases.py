@@ -3,6 +3,7 @@ device time by named scope (``lib.phases``); on events made by hand, on a
 2.56 s window of qwen2-1.5b.chat recorded on a TPU v5e, and on the xplane
 of a span recorded here.  Also pins what ``lib.trace`` reads of the
 benchmark's first recorded trace, so that a change there shows."""
+import dataclasses
 import glob
 import json
 import sys
@@ -14,7 +15,7 @@ BENCH = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(BENCH))
 
 import phases as phases_cli  # noqa: E402
-from lib import trace  # noqa: E402
+from lib import measure, trace  # noqa: E402
 from lib.phases import (Events, _nest, export, load_export,  # noqa: E402
                         read_xplane, reduce_phases, scope_of)
 
@@ -205,3 +206,117 @@ def test_first_recorded_trace_reduces_as_before():
         ["convert_element_type.59 convert bf16[28,1536,8960]",
          0.010771771000000001],
         ["convert_element_type.58 convert bf16[28,8960,1536]", 0.010564152]]
+
+
+#: the per-layer metrics read from the program's spans and scopes
+PHASE_METRICS = ("engine.step_idle_ms", "engine.programs_per_step",
+                 "model.decode_attention_share",
+                 "model.decode_weight_cast_ms")
+
+
+def _run_of(red):
+    from types import SimpleNamespace
+
+    return SimpleNamespace(trace=red)
+
+
+def test_export_reduces_with_its_phases(recorded):
+    """A kept export with the program's spans and scopes reduces to the
+    same ``phases`` as its events, and the idle rows of its breakdown are
+    the harness's, then the spans', ten at most; the first recorded
+    trace, which has neither, reduces with none."""
+    ev, ph, red = recorded
+    got = trace.reduce_export(str(RECORDED))
+    assert got.phases == ph
+    got.phases = None
+    assert got == red
+    got.phases = ph
+    rows = got.breakdown()["idle_gaps"]
+    harness = red.breakdown()["idle_gaps"]
+    assert rows == (harness + ph.idle_rows())[:10]
+    assert len(harness) < len(rows) == 10
+    first = trace.reduce_export(str(FIRST))
+    assert first.phases is None
+    assert first == trace.reduce_planes(trace.load_export(str(FIRST)))
+
+
+def test_a_reader_added_as_a_file_reads_the_phases(tmp_path, recorded):
+    """A reader added as a new file finds the program's phases on the
+    run's trace: the recorded window's per-step values."""
+    _, ph, _ = recorded
+    (tmp_path / "steps.py").write_text(
+        "def read(run):\n    return run.trace.phases.per_step()\n")
+    red = trace.reduce_export(str(RECORDED))
+    assert measure.reader(tmp_path, "steps")(_run_of(red)) == ph.per_step()
+
+
+@pytest.mark.parametrize("name", PHASE_METRICS)
+def test_phase_readers(recorded, name):
+    """Each reader of the program's phases gives the recorded window's
+    per-step value, and nothing where the trace has no spans."""
+    _, ph, _ = recorded
+    read = measure.reader(BENCH / "metrics", name)
+    assert read(_run_of(trace.reduce_export(str(RECORDED)))) == \
+        ph.per_step()[name]
+    assert read(_run_of(trace.reduce_export(str(FIRST)))) is None
+    assert read(_run_of(None)) is None
+
+
+def _write_xplane(ev: Events, path) -> None:
+    """``ev`` as a profiler's ``.xplane.pb``: the host events on one line,
+    each device's modules and operations on theirs, every operation's
+    scope path and program id as stats of its metadata."""
+    from lib.phases import _xspace_type
+
+    space = _xspace_type()()
+
+    def add_event(plane, line, name, s, e, stats=()):
+        key = len(plane.event_metadata) + 1
+        md = plane.event_metadata.add(key=key)
+        md.value.name = name
+        for stat, kind, value in stats:
+            md.value.stats.add(metadata_id=stat, **{kind: value})
+        line.events.add(metadata_id=key, offset_ps=1000 * s,
+                        duration_ps=1000 * (e - s))
+
+    host = space.planes.add(name="/host:CPU")
+    line = host.lines.add(name="python", timestamp_ns=0)
+    for name, s, e in [(trace.WINDOW, *ev.window)] + ev.host:
+        add_event(host, line, name, s, e)
+    for pname, dev in ev.devices.items():
+        plane = space.planes.add(name=pname)
+        plane.stat_metadata.add(key=1).value.name = "tf_op"
+        plane.stat_metadata.add(key=2).value.name = "program_id"
+        ids = {}
+        mods = plane.lines.add(name="XLA Modules", timestamp_ns=0)
+        for name, s, e in dev["modules"]:
+            base, _, pid = name.rstrip(")").rpartition("(")
+            ids[base] = int(pid)
+            add_event(plane, mods, name, s, e)
+        ops = plane.lines.add(name="XLA Ops", timestamp_ns=0)
+        for name, s, e, module, scope in dev["ops"]:
+            tf_op = "/".join(["jit(f)"] + [scope] * bool(scope) + ["op"])
+            add_event(plane, ops, name, s, e,
+                      [(1, "str_value", tf_op),
+                       (2, "uint64_value", ids[module])])
+    path.write_bytes(space.SerializeToString())
+
+
+def test_profile_and_its_export_reduce_with_phases(tmp_path):
+    """``reduce_file`` of a profile keeps ``lib.phases``' reduction of it
+    as ``phases``, beside the reduction ``jax.profiler.ProfileData``'s
+    planes give; its ``export`` reduces to the same."""
+    ev = _hand_made()
+    path = tmp_path / "hand.xplane.pb"
+    _write_xplane(ev, path)
+    red = trace.reduce_file(str(path))
+    assert red.phases == reduce_phases(ev)
+    assert red.busy_s == red.phases.busy_s
+    assert red.idle_by_label == trace.reduce_planes(ev.planes()).idle_by_label
+    # the same reduction as of the planes jax.profiler.ProfileData reads
+    from jax.profiler import ProfileData
+    plain = trace.reduce_planes(ProfileData.from_file(str(path)).planes)
+    assert red == dataclasses.replace(plain, phases=red.phases)
+    out = tmp_path / "hand.json.gz"
+    trace.export(str(path), str(out))
+    assert trace.reduce_export(str(out)) == red

@@ -101,9 +101,22 @@ def test_a_cell_added_as_files_only(tmp_path, bench):
     faults = validate(bench, root)
     assert any("no traffic file" in f for f in faults)
     assert any("chips must be 1 or 4" in f for f in faults)
+    conf = json.loads((BENCH / "configs" / "qwen2-1.5b.json").read_text())
+    (root / "chipbench" / "configs" / "q.json").write_text(
+        json.dumps(dict(conf, reference="nothing")))
+    bench["configs"].append(dict(bench["configs"][0], name="q",
+                                 file="chipbench/configs/q.json"))
+    assert any("no reference lib/nothing.py" in f
+               for f in validate(bench, root))
 
 
-@pytest.mark.parametrize("name", ["qwen2-1.5b"])
+#: the configurations of BENCHMARK.json, read as the tests are collected
+CONFIGS = [c["name"] for c in load_benchmark(ROOT)["configs"]]
+MULTIPLIERS = ("embedding_multiplier", "attention_multiplier",
+               "residual_multiplier", "logits_scaling")
+
+
+@pytest.mark.parametrize("name", CONFIGS)
 def test_config_file_is_what_the_program_runs(bench, name):
     from repro.configs import get_config
 
@@ -112,12 +125,20 @@ def test_config_file_is_what_the_program_runs(bench, name):
     assert sorted(entry["reduced"]) == sorted(data["reduced"])
     cell = next(w["name"] for w in bench["workloads"]
                 if w["config"] == name)
-    cfg = load_cell(cell, ROOT).model.program_config()
+    spec = load_cell(cell, ROOT).model
+    cfg = spec.program_config()
     published = get_config(data["program_arch"])
     for field in ("d_model", "n_heads", "n_kv_heads", "head_dim",
                   "vocab_size", "d_ff", "moe_d_ff", "n_experts",
                   "experts_per_token", "qkv_bias", "tie_embeddings"):
         assert getattr(cfg, field) == getattr(published, field), field
+    # a multiplier the program has runs as the file states it, which is
+    # what the program registers for the architecture
+    for key in MULTIPLIERS:
+        if hasattr(published, key):
+            assert getattr(cfg, key) == pytest.approx(spec.multiplier(key))
+            assert getattr(cfg, key) == pytest.approx(getattr(published,
+                                                              key)), key
     changed = {"n_layers": "num_hidden_layers"}
     for field, key in changed.items():
         if getattr(cfg, field) != getattr(published, field):
@@ -138,24 +159,95 @@ GRANITE = {"program_arch": "granite-moe-3b-a800m", "hidden_size": 1536,
            "hidden_act": "silu", "embedding_multiplier": 12.0,
            "attention_multiplier": 0.015625, "residual_multiplier": 0.22,
            "logits_scaling": 6.0}
+#: the plain pre-norm block's values of the four (attention: 1/sqrt(64))
+PLAIN_BLOCK = {"embedding_multiplier": 1.0, "attention_multiplier": 0.125,
+               "residual_multiplier": 1.0, "logits_scaling": 1.0}
 
 
-@pytest.mark.parametrize("key, plain", [
-    ("embedding_multiplier", 1.0), ("attention_multiplier", 0.125),
-    ("residual_multiplier", 1.0), ("logits_scaling", 1.0)])
-def test_program_that_cannot_run_a_key_is_refused(key, plain):
-    """The program has no Granite multipliers: each one as published is
+def _stand_in(monkeypatch, multipliers: bool):
+    """Make ``program_config`` obtain, for Granite, a stand-in of the
+    program's configuration: the registered one's fields without the four
+    multipliers, or with them at the plain block's values."""
+    import dataclasses
+
+    import repro.configs
+    from repro.configs.base import ModelConfig
+
+    real = repro.configs.get_config("granite-moe-3b-a800m")
+    fields = [(f.name, f.type) for f in dataclasses.fields(ModelConfig)
+              if f.name not in MULTIPLIERS]
+    values = {name: getattr(real, name) for name, _ in fields}
+    if multipliers:
+        fields += [(key, float) for key in MULTIPLIERS]
+        values.update(PLAIN_BLOCK)
+    cls = dataclasses.make_dataclass("StandIn", fields, frozen=True,
+                                     kw_only=True)
+    monkeypatch.setattr(repro.configs, "get_config",
+                        lambda arch: cls(**values))
+
+
+@pytest.mark.parametrize("key, plain", list(PLAIN_BLOCK.items()))
+def test_program_that_cannot_run_a_key_is_refused(monkeypatch, key, plain):
+    """A program with no Granite multipliers: each one as published is
     refused, and the plain block's value of all four runs."""
     from lib.spec import ModelSpec
 
+    _stand_in(monkeypatch, multipliers=False)
     with pytest.raises(ValueError, match="the program has no"):
         ModelSpec("g", GRANITE).program_config()
-    data = dict(GRANITE, embedding_multiplier=1.0,
-                attention_multiplier=0.125, residual_multiplier=1.0,
-                logits_scaling=1.0)
-    assert ModelSpec("g", data).program_config().n_layers == 16
+    data = dict(GRANITE, **PLAIN_BLOCK)
+    cfg = ModelSpec("g", data).program_config()
+    assert cfg.n_layers == 16 and not hasattr(cfg, key)
     with pytest.raises(ValueError, match=key):
         ModelSpec("g", dict(data, **{key: 2 * plain})).program_config()
+
+
+@pytest.mark.parametrize("key", MULTIPLIERS)
+def test_program_that_has_a_key_runs_it_as_published(monkeypatch, key):
+    """A program with the four multipliers: each published value reaches
+    its field unchanged, and a file without the key runs the plain
+    block's value."""
+    from lib.spec import ModelSpec
+
+    _stand_in(monkeypatch, multipliers=True)
+    cfg = ModelSpec("g", GRANITE).program_config()
+    assert getattr(cfg, key) == GRANITE[key]
+    assert cfg.n_layers == 16 and cfg.moe_d_ff == 512
+    data = {k: v for k, v in GRANITE.items() if k != key}
+    assert getattr(ModelSpec("g", data).program_config(), key) == \
+        PLAIN_BLOCK[key]
+
+
+@pytest.mark.parametrize("key", ["sliding_window", "partial_rotary_factor"])
+def test_unknown_published_key_is_refused(key):
+    """A published key that neither the benchmark nor the configuration's
+    reference runs is refused, not dropped: the program would run its own
+    registered value while the reference runs the file's."""
+    from lib.spec import ModelSpec
+
+    spec = ModelSpec("g", dict(GRANITE, **PLAIN_BLOCK, **{key: 4096}))
+    with pytest.raises(ValueError, match=f"published keys \\['{key}'\\]"):
+        spec.program_config()
+
+
+def test_reference_module_is_named_by_the_configuration(tmp_path):
+    """``reference`` names a module under the checkout's ``lib/``; one
+    that is not there, or is no module name, is refused."""
+    from lib.spec import ModelSpec
+
+    lib = tmp_path / "lib"
+    lib.mkdir()
+    (lib / "other_ref.py").write_text("PROGRAM_FIELD = {'window': 'w'}\n")
+    spec = ModelSpec("g", dict(GRANITE, reference="other_ref"), lib_dir=lib)
+    assert spec.reference.PROGRAM_FIELD == {"window": "w"}
+    assert ModelSpec("g", GRANITE).reference.__file__ == str(
+        BENCH / "lib" / "reference.py")
+    with pytest.raises(FileNotFoundError):
+        ModelSpec("g", dict(GRANITE, reference="nothing"),
+                  lib_dir=lib).reference
+    with pytest.raises(ValueError, match="not a module name"):
+        ModelSpec("g", dict(GRANITE, reference="../reference"),
+                  lib_dir=lib).reference
 
 
 def test_run_fails_off_a_tpu():

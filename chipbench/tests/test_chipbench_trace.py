@@ -96,3 +96,27 @@ def test_recorded_trace():
                       "other"}
     assert sum(r.idle_by_label.values()) == pytest.approx(
         r.window_s - r.busy_s, rel=1e-6)
+
+
+def _nested_labels():
+    """The hand-made trace with host annotations that nest and overlap."""
+    planes = _hand_made()
+    host = planes[1]
+    host.lines[0].events += [NS(name="submit", start_ns=2, duration_ns=30),
+                             NS(name="bookkeeping", start_ns=14,
+                                duration_ns=4)]
+    return planes
+
+
+@pytest.mark.parametrize("planes", [
+    lambda: load_export(str(RECORDED)),
+    lambda: load_export(str(RECORDED).replace("events", "phases")),
+    _nested_labels])
+def test_idle_labels_as_a_full_scan(monkeypatch, planes):
+    """Each idle gap's scan of the annotations starts past those that end
+    before it; the reduction is that of a scan from the first."""
+    from lib import trace
+
+    fast = reduce_planes(planes())
+    monkeypatch.setattr(trace.bisect, "bisect_right", lambda *args: 0)
+    assert reduce_planes(planes()) == fast
