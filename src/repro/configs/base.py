@@ -37,11 +37,20 @@ class ModelConfig:
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     logit_softcap: float = 0.0
+    # Granite's multipliers; each default is the plain pre-norm block's, and
+    # ``LM`` applies none at its default
+    embedding_multiplier: float = 1.0   # token embeddings times this
+    attention_multiplier: float = 0.0   # attention scores times this;
+    #                                     0 -> head_dim ** -0.5
+    residual_multiplier: float = 1.0    # each residual branch times this
+    logits_scaling: float = 1.0         # the head's logits divided by this
 
     # --- MoE ---------------------------------------------------------------
     n_experts: int = 0
     experts_per_token: int = 0
     moe_d_ff: int = 0
+    # per-expert slots over the even share; only the mesh paths (the pjit
+    # einsum and ``apply_moe_ep``) have capacity and drop what overflows it
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
 
@@ -82,6 +91,9 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim == 0:
             object.__setattr__(self, "head_dim", self.d_model // max(self.n_heads, 1))
+        if self.attention_multiplier == 0.0:
+            object.__setattr__(self, "attention_multiplier",
+                               self.head_dim ** -0.5)
         if not self.block_pattern:
             kind = "moe" if self.n_experts else "attn"
             object.__setattr__(self, "block_pattern", tuple([kind] * self.n_layers))

@@ -354,7 +354,11 @@ def model_gemm_shapes(cfg, tokens: int = 4096) -> list[GemmShape]:
         shapes.append(GemmShape(tokens, 2 * cfg.d_ff, d, dtype="bf16"))  # gate+up
         shapes.append(GemmShape(tokens, d, cfg.d_ff, dtype="bf16"))      # down
     if getattr(cfg, "n_experts", 0):
-        per_e = max(1, tokens * cfg.experts_per_token // cfg.n_experts)
+        # the token-major grouped GEMM runs tokens x k rows over the experts
+        # they hit, E (1 - (1 - k/E)^tokens) of them under even routing
+        e, k = cfg.n_experts, cfg.experts_per_token
+        hit = e * (1.0 - (1.0 - k / e) ** tokens)
+        per_e = max(1, math.ceil(tokens * k / hit - 1e-9))
         shapes.append(GemmShape(per_e, 2 * cfg.moe_d_ff, d, dtype="bf16"))
         shapes.append(GemmShape(per_e, d, cfg.moe_d_ff, dtype="bf16"))
     shapes.append(GemmShape(tokens, cfg.vocab_size, d, dtype="bf16"))    # logits

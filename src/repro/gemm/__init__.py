@@ -40,7 +40,6 @@ from repro.gemm.planner import (
     cached_plans,
     clear_plan_cache,
     default_execute_backend,
-    grouped_matmul,
     matmul,
     plan,
     plan_cache_stats,
@@ -59,7 +58,7 @@ __all__ = [
     "VariantChoice",
     "backends", "cached_plans", "clear_plan_cache",
     "default_execute_backend", "dtype_tag",
-    "get_backend", "grouped_matmul", "matmul", "plan", "plan_cache_stats",
+    "get_backend", "matmul", "plan", "plan_cache_stats",
     "plan_many", "plan_model_gemms", "register_backend",
     "reset_plan_cache_stats", "save_cache", "sweep", "warm_cache",
 ]

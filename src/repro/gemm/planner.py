@@ -223,21 +223,6 @@ def matmul(x, w, *, backend: str | None = None, interpret: bool = False):
     return out if x.ndim == 2 else out.reshape(*lead, n)
 
 
-def grouped_matmul(x, w, *, interpret: bool = False):
-    """Planned grouped (expert-batched) matmul: ``(..., E, C, D) @ (E, D, F)``.
-
-    Routes through ``kernels.ops.grouped_gemm`` (Pallas on TPU / interpret,
-    jnp reference elsewhere), vmapped over any extra leading batch dims.
-    """
-    from repro.kernels import ops
-    if x.ndim == 3:
-        return ops.grouped_gemm(x, w, interpret=interpret)
-    lead = x.shape[:-3]
-    x4 = x.reshape((-1,) + x.shape[-3:])
-    out = jax.vmap(lambda xb: ops.grouped_gemm(xb, w, interpret=interpret))(x4)
-    return out.reshape(lead + out.shape[-3:])
-
-
 def plan_model_gemms(cfg, *, tokens: int = 4096,
                      backend: str = "analytic-tpu",
                      **plan_kwargs) -> list[GemmPlan]:

@@ -60,12 +60,14 @@ def matmul(a, b, *, tile: TileConfig | None = None,
     return plan.execute(a, b, interpret=interpret, force=force_pallas)
 
 
-def grouped_gemm(x, w, *, block_c: int = 128, block_f: int = 128,
+def grouped_gemm(x, w, group_sizes, *, block_m: int,
                  interpret: bool = False):
-    """x: (E, C, D) @ w: (E, D, F) -> (E, C, F) (MoE expert FFN)."""
+    """x: (R, D) rows sorted by expert @ w: (E, D, F) -> (R, F), expert
+    ``e`` on its ``group_sizes[e]`` rows (multiples of ``block_m``); the
+    MoE expert FFN (``kernels.grouped_gemm``)."""
     if not (_on_tpu() or interpret):
-        return ref.grouped_gemm_ref(x, w)
-    return grouped_gemm_kernel(x, w, block_c=block_c, block_f=block_f,
+        return ref.grouped_gemm_ref(x, w, group_sizes)
+    return grouped_gemm_kernel(x, w, group_sizes, block_m=block_m,
                                interpret=interpret)
 
 

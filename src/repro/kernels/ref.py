@@ -28,10 +28,13 @@ def gemm_ref_streamed(a, b, c, bk: int):
     return c
 
 
-def grouped_gemm_ref(x, w):
-    """x: (E, C, D); w: (E, D, F) -> (E, C, F)."""
-    return jnp.einsum("ecd,edf->ecf", x, w,
-                      preferred_element_type=jnp.float32).astype(x.dtype)
+def grouped_gemm_ref(x, w, group_sizes):
+    """x: (R, D) rows sorted by expert; w: (E, D, F); group_sizes: (E,) ->
+    (R, F): each group's rows times its expert's weights, zero past the
+    last group (``grouped_gemm_kernel``'s contract; differentiable)."""
+    return jax.lax.ragged_dot(x, w, group_sizes.astype(jnp.int32),
+                              preferred_element_type=jnp.float32
+                              ).astype(x.dtype)
 
 
 def flash_attention_ref(q, k, v, *, causal: bool = True):

@@ -135,16 +135,17 @@ def _repeat_kv(k, n_rep: int):
 
 
 def blockwise_attention(q, k, v, *, chunk: int, causal: bool,
-                        prefix_len: int = 0, q_offset: int = 0):
+                        scale: float, prefix_len: int = 0,
+                        q_offset: int = 0):
     """Online-softmax attention over KV chunks; O(S*chunk) memory.
 
     q: (B, Sq, H, hd); k, v: (B, Skv, H, hd) (KV already repeated to H).
     ``causal`` masks with query positions offset by ``q_offset``;
     ``prefix_len`` positions attend bidirectionally (prefix-LM / PaliGemma).
+    Scores are scaled by ``scale`` (``cfg.attention_multiplier``).
     """
     b, sq, h, hd = q.shape
     skv = k.shape[1]
-    scale = hd ** -0.5
     qf = (q * scale).astype(jnp.float32)
     chunk = min(chunk, skv)
     n_chunks = math.ceil(skv / chunk)
@@ -197,7 +198,8 @@ def apply_attention(params, x, cfg, mesh: MeshInfo, *, positions=None,
     k = _repeat_kv(k, n_rep)
     v = _repeat_kv(v, n_rep)
     out = blockwise_attention(q, k, v, chunk=cfg.attn_chunk, causal=True,
-                              prefix_len=prefix_len)
+                              prefix_len=prefix_len,
+                              scale=cfg.attention_multiplier)
     return jnp.einsum("bshe,hed->bsd", out, params["wo"])
 
 
@@ -248,6 +250,7 @@ def _quant_kv(x):
 
 def decode_attention(params, cache, x, cfg, mesh: MeshInfo, *, pos):
     """One-token decode.  x: (B, 1, D); pos: scalar int32 (current length).
+    Scores are scaled by ``cfg.attention_multiplier``.
 
     Returns (out (B, 1, D), new_cache).  Softmax over the (possibly
     data-sharded) cache sequence axis — XLA's SPMD partitioner lowers the
@@ -290,7 +293,7 @@ def decode_attention(params, cache, x, cfg, mesh: MeshInfo, *, pos):
     hkv = k_cache.shape[2]
     n_rep = hq // hkv
     skv = k_cache.shape[1]
-    scale = cfg.head_dim ** -0.5
+    scale = cfg.attention_multiplier
     qg = (q * scale).reshape(b, 1, hkv, n_rep, cfg.head_dim
                              ).astype(jnp.float32)
     if quant:

@@ -121,17 +121,26 @@ def init_embedding(key, cfg, mesh: MeshInfo, dtype):
 
 
 def embed_tokens(params, token_ids, cfg):
+    """Token embeddings, times ``cfg.embedding_multiplier`` (in f32, one
+    rounding) where it is not 1."""
     with jax.named_scope("embed"):
-        return jnp.take(params["table"], token_ids, axis=0)
+        x = jnp.take(params["table"], token_ids, axis=0)
+        if cfg.embedding_multiplier != 1.0:
+            x = (x.astype(jnp.float32) * cfg.embedding_multiplier
+                 ).astype(x.dtype)
+        return x
 
 
 def logits_head(params, x, cfg):
-    """x: (..., d) -> (..., padded_vocab); soft-capped if configured."""
+    """x: (..., d) -> (..., padded_vocab); divided by ``cfg.logits_scaling``
+    (in f32) where it is not 1; soft-capped if configured."""
     with jax.named_scope("head"):
         if cfg.tie_embeddings:
             logits = gemm_api.matmul(x, params["table"].T)
         else:
             logits = gemm_api.matmul(x, params["unembed"])
+        if cfg.logits_scaling != 1.0:
+            logits = logits.astype(jnp.float32) / cfg.logits_scaling
         if cfg.logit_softcap:
             c = cfg.logit_softcap
             logits = c * jnp.tanh(logits / c)

@@ -80,6 +80,14 @@ def _init_block(key, kind: str, cfg, mesh, dtype):
     raise ValueError(kind)
 
 
+def _residual(x, branch, cfg):
+    """x plus an attention, MLP or MoE branch times
+    ``cfg.residual_multiplier`` (nothing applied where it is 1)."""
+    if cfg.residual_multiplier != 1.0:
+        branch = branch * cfg.residual_multiplier
+    return x + branch
+
+
 def _apply_block(params, kind: str, x, cfg, mesh, *, prefix_len=0):
     """Training/prefill-forward; returns (x, aux_loss, cache_out)."""
     aux = 0.0
@@ -93,9 +101,10 @@ def _apply_block(params, kind: str, x, cfg, mesh, *, prefix_len=0):
             n_rep = q.shape[2] // k.shape[2]
             out = attn.blockwise_attention(
                 q, attn._repeat_kv(k, n_rep), attn._repeat_kv(v, n_rep),
-                chunk=cfg.attn_chunk, causal=True, prefix_len=prefix_len)
+                chunk=cfg.attn_chunk, causal=True, prefix_len=prefix_len,
+                scale=cfg.attention_multiplier)
             out = jnp.einsum("bshe,hed->bsd", out, params["attn"]["wo"])
-        x = x + out
+        x = _residual(x, out, cfg)
         cache = {"k": k, "v": v}
         if kind == "moe":
             h2 = layers.apply_norm(params["norm2"], x, cfg)
@@ -104,12 +113,12 @@ def _apply_block(params, kind: str, x, cfg, mesh, *, prefix_len=0):
                     y, aux = moe.apply_moe_ep(params["moe"], h2, cfg, mesh)
                 else:
                     y, aux = moe.apply_moe(params["moe"], h2, cfg, mesh)
-            x = x + y
+            x = _residual(x, y, cfg)
         elif cfg.d_ff:
             h2 = layers.apply_norm(params["norm2"], x, cfg)
             with jax.named_scope("mlp"):
                 y = layers.apply_mlp(params["mlp"], h2, cfg)
-            x = x + y
+            x = _residual(x, y, cfg)
         return x, aux, cache
     if kind == "mamba2":
         h = layers.apply_norm(params["norm1"], x, cfg)
@@ -132,17 +141,17 @@ def _decode_block(params, kind: str, cache, x, cfg, mesh, *, pos):
         with jax.named_scope("attention"):
             out, cache = attn.decode_attention(params["attn"], cache, h, cfg,
                                                mesh, pos=pos)
-        x = x + out
+        x = _residual(x, out, cfg)
         if kind == "moe":
             h2 = layers.apply_norm(params["norm2"], x, cfg)
             with jax.named_scope("moe"):
                 y, _ = moe.apply_moe(params["moe"], h2, cfg, mesh)
-            x = x + y
+            x = _residual(x, y, cfg)
         elif cfg.d_ff:
             h2 = layers.apply_norm(params["norm2"], x, cfg)
             with jax.named_scope("mlp"):
                 y = layers.apply_mlp(params["mlp"], h2, cfg)
-            x = x + y
+            x = _residual(x, y, cfg)
         return x, cache
     if kind == "mamba2":
         h = layers.apply_norm(params["norm1"], x, cfg)

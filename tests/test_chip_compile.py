@@ -99,16 +99,24 @@ def test_budget_edge_tile_compiles(one_chip):
                   tile=TileConfig(2048, 2048, 2048, GridOrder.K_INNER))
 
 
-@pytest.mark.parametrize("cap", [8, 512], ids=["decode", "prefill"])
+@pytest.mark.parametrize("tokens", [16, 1024], ids=["decode", "prefill"])
 @pytest.mark.parametrize("proj", ["up", "down"])
-def test_granite_grouped_gemm_compiles(one_chip, cap, proj):
+def test_granite_grouped_gemm_compiles(one_chip, tokens, proj):
+    """The token-major grouped GEMM at granite's widths, for a decode step
+    of 16 slots and a 1024-token prefill bucket, top-8 over 40 experts."""
+    from repro.kernels.grouped_gemm import padded_rows, row_tile
     e, d, f = _GRANITE.n_experts, _GRANITE.d_model, _GRANITE.moe_d_ff
     d_in, d_out = (d, f) if proj == "up" else (f, d)
-    x = jax.ShapeDtypeStruct((e, cap, d_in), jnp.bfloat16, sharding=one_chip)
+    a = tokens * _GRANITE.experts_per_token
+    bm = row_tile(a, e)
+    x = jax.ShapeDtypeStruct((padded_rows(a, e, bm), d_in), jnp.bfloat16,
+                             sharding=one_chip)
     w = jax.ShapeDtypeStruct((e, d_in, d_out), jnp.bfloat16,
                              sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((e,), jnp.int32, sharding=one_chip)
     with mock.patch.object(ops, "_on_tpu", return_value=True):
-        compiled = jax.jit(ops.grouped_gemm).lower(x, w).compile()
+        compiled = jax.jit(lambda x, w, s: ops.grouped_gemm(
+            x, w, s, block_m=bm)).lower(x, w, sizes).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 

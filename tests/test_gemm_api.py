@@ -244,10 +244,18 @@ def test_matmul_helper_folds_leading_dims():
 
 
 def test_grouped_matmul_helper_matches_einsum():
-    x = jnp.array(RNG.normal(size=(2, 3, 16, 24)), jnp.float32)
+    """The grouped GEMM's one dispatch (``kernels.ops.grouped_gemm``, the
+    jnp reference off a TPU): rows sorted by expert (groups of 16, 0 and
+    32 rows, then rows past the groups), each group times its expert's
+    weights, zeros after."""
+    from repro.kernels import ops
+
+    x = jnp.array(RNG.normal(size=(64, 24)), jnp.float32)
     w = jnp.array(RNG.normal(size=(3, 24, 8)), jnp.float32)
-    got = gemm.grouped_matmul(x, w)
-    want = jnp.einsum("becd,edf->becf", x, w)
+    sizes = jnp.array([16, 0, 32], jnp.int32)
+    got = ops.grouped_gemm(x, w, sizes, block_m=16)
+    want = jnp.concatenate([x[:16] @ w[0], x[16:48] @ w[2],
+                            jnp.zeros((16, 8))])
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
 

@@ -127,32 +127,94 @@ def test_k_inner_int8_exact_vs_k_outer():
 # Grouped GEMM (MoE)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("e,c,d,f,dt", [
-    (4, 128, 256, 128, "float32"),
-    (8, 256, 128, 256, "bfloat16"),
-    (2, 128, 512, 384, "float32"),
-])
-def test_grouped_gemm_matches_ref(e, c, d, f, dt):
-    x, w = _rand((e, c, d), dt), _rand((e, d, f), dt)
-    got = grouped_gemm_kernel(x, w, block_c=128, block_f=128, block_k=128,
-                              interpret=True)
-    want = ref.grouped_gemm_ref(x, w)
+def _routed(experts, n_experts, d, dt, seed=0):
+    """Rows in the token-major layout for the assignments ``experts``:
+    (x rows, the row of each assignment, group sizes, block_m)."""
+    from repro.kernels.grouped_gemm import padded_rows, row_tile
+    from repro.models.moe import token_major_layout
+    experts = jnp.asarray(experts, jnp.int32)
+    a = experts.shape[0]
+    bm = row_tile(a, n_experts)
+    rows = padded_rows(a, n_experts, bm)
+    row_of, sizes = token_major_layout(experts, n_experts, bm)
+    v = jnp.array(np.random.default_rng(seed).normal(size=(a, d)), dt)
+    x = jnp.zeros((rows, d), dt).at[row_of].set(v)
+    return x, v, row_of, sizes, bm
+
+
+def _check_grouped(experts, e, d, f, dt):
+    """The kernel against its jnp reference on every row (zeros past the
+    groups included), and each assignment's row against its own expert's
+    product."""
+    x, v, row_of, sizes, bm = _routed(experts, e, d, dt)
+    w = _rand((e, d, f), dt)
+    got = grouped_gemm_kernel(x, w, sizes, block_m=bm, interpret=True)
+    want = ref.grouped_gemm_ref(x, w, sizes)
+    tol = 2e-2 if dt == "bfloat16" else 1e-4
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
-                               rtol=2e-2 if dt == "bfloat16" else 1e-5,
-                               atol=2e-2 if dt == "bfloat16" else 1e-4)
+                               rtol=tol, atol=tol)
+    own = jnp.einsum("ad,adf->af", v.astype(jnp.float32),
+                     w.astype(jnp.float32)[jnp.asarray(experts)])
+    np.testing.assert_allclose(np.asarray(got[row_of], np.float32),
+                               np.asarray(own), rtol=tol, atol=tol * 10)
+    return got, sizes
+
+
+@pytest.mark.parametrize("a,e,d,f,dt", [
+    (512, 4, 256, 128, "float32"),
+    (2048, 8, 128, 256, "bfloat16"),
+    (256, 2, 512, 384, "float32"),
+])
+def test_grouped_gemm_matches_ref(a, e, d, f, dt):
+    _check_grouped(RNG.integers(0, e, size=a), e, d, f, dt)
+
+
+@pytest.mark.parametrize("case", ["empty_experts", "one_expert_all_rows",
+                                  "groups_not_tile_multiples",
+                                  "decode_16x8", "prefill_bucket"])
+def test_grouped_gemm_routing(case):
+    """The routings the MoE layer hands the kernel: experts with no row,
+    one expert with every row, groups that do not fill their last tile,
+    a decode step (16 tokens x top-8 over 40 experts) and a prefill bucket
+    (128 tokens x top-8)."""
+    e, d, f = 40, 256, 128
+    if case == "empty_experts":
+        experts = RNG.choice([3, 17, 39], size=64)
+    elif case == "one_expert_all_rows":
+        e, experts = 8, np.full(96, 5)
+    elif case == "groups_not_tile_multiples":
+        e, experts = 4, np.repeat([0, 1, 2, 3], [1, 17, 15, 33])
+    elif case == "decode_16x8":
+        experts = np.stack([RNG.choice(e, 8, replace=False)
+                            for _ in range(16)]).ravel()
+    else:
+        experts = np.stack([RNG.choice(e, 8, replace=False)
+                            for _ in range(128)]).ravel()
+    got, sizes = _check_grouped(experts, e, d, f, "bfloat16")
+    counts = np.bincount(experts, minlength=e)
+    bm = int(max(sizes)) if case == "one_expert_all_rows" else None
+    assert (np.asarray(sizes) >= counts).all()
+    assert ((np.asarray(sizes) == 0) == (counts == 0)).all()
+    live = int(np.asarray(sizes).sum())
+    assert not np.asarray(got[live:], np.float32).any()
+    if bm is not None:
+        assert live == bm and np.asarray(sizes)[5] == live
 
 
 def test_grouped_gemm_expert_isolation():
-    """Each expert's output depends only on its own weights."""
-    e, c, d, f = 4, 128, 128, 128
-    x = _rand((e, c, d), "float32")
+    """Each expert's rows depend only on its own weights."""
+    e, d, f = 4, 128, 128
+    experts = RNG.integers(0, e, size=128)
+    x, _, row_of, sizes, bm = _routed(experts, e, d, "float32")
     w = _rand((e, d, f), "float32")
     w2 = w.at[2].set(0.0)
-    y1 = np.asarray(grouped_gemm_kernel(x, w, interpret=True))
-    y2 = np.asarray(grouped_gemm_kernel(x, w2, interpret=True))
-    assert np.allclose(y2[2], 0.0)
-    np.testing.assert_allclose(y1[[0, 1, 3]], y2[[0, 1, 3]])
+    y1 = np.asarray(grouped_gemm_kernel(x, w, sizes, block_m=bm,
+                                        interpret=True))[row_of]
+    y2 = np.asarray(grouped_gemm_kernel(x, w2, sizes, block_m=bm,
+                                        interpret=True))[row_of]
+    assert np.allclose(y2[experts == 2], 0.0)
+    np.testing.assert_allclose(y1[experts != 2], y2[experts != 2])
 
 
 # ---------------------------------------------------------------------------
@@ -191,7 +253,8 @@ def test_flash_attention_matches_model_blockwise():
     q, k, v = (_rand((2, 128, 2, 64), "float32") for _ in range(3))
     a = flash_attention_fwd(q, k, v, causal=True, block_q=64, block_k=64,
                             interpret=True)
-    b = blockwise_attention(q, k, v, chunk=64, causal=True)
+    b = blockwise_attention(q, k, v, chunk=64, causal=True,
+                            scale=64 ** -0.5)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
                                atol=2e-5)
 

@@ -232,7 +232,7 @@ def test_blockwise_attention_matches_naive(seed, s, chunk, prefix):
     k = jnp.array(rng.normal(size=(b, s, h, d)), jnp.float32)
     v = jnp.array(rng.normal(size=(b, s, h, d)), jnp.float32)
     got = blockwise_attention(q, k, v, chunk=chunk, causal=True,
-                              prefix_len=min(prefix, s))
+                              scale=d ** -0.5, prefix_len=min(prefix, s))
     want = _naive_attention(q, k, v, causal=True, prefix_len=min(prefix, s))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
@@ -283,15 +283,33 @@ def test_moe_gates_normalised_and_capacity_exact():
 
 
 def test_moe_capacity_drops_bounded():
-    """With capacity factor 1.0+, dropped-token output is the residual only —
-    outputs stay finite and bounded."""
+    """On one device the expert layer drops no token: where per-sequence
+    capacity would have overflowed (16 slots per expert for 32 tokens x
+    top-2), every token's output is its k experts' gated sum, never the
+    residual alone."""
+    import numpy as np
+    from repro.models.moe import _capacity, _route, init_moe
     cfg = get_config("granite-moe-3b-a800m", smoke=True)
-    from repro.models.moe import init_moe
     p, _ = split_params(init_moe(jax.random.key(0), cfg, HOST_MESH,
                                  jnp.float32))
     x = jax.random.normal(jax.random.key(1), (4, 32, cfg.d_model), jnp.float32)
+    r0 = p["router"][:, 0]                       # skew every token to expert 0
+    x = x + 4.0 * r0 / jnp.linalg.norm(r0)
     y, aux = apply_moe(p, x, cfg)
-    assert jnp.all(jnp.isfinite(y))
+    gates, experts, _ = _route(p, x, cfg)
+    loads = np.bincount(np.asarray(experts[0]).ravel(), minlength=5)
+    assert loads.max() > _capacity(32, cfg)      # capacity would drop here
+    xf = x.reshape(-1, cfg.d_model)
+    h = jax.nn.silu(jnp.einsum("td,edf->tef", xf, p["w_gate"])) * \
+        jnp.einsum("td,edf->tef", xf, p["w_up"])
+    per_expert = jnp.einsum("tef,efd->ted", h, p["w_down"])
+    picked = jnp.take_along_axis(per_expert,
+                                 experts.reshape(-1, 2)[..., None], axis=1)
+    want = (picked * gates.reshape(-1, 2, 1)).sum(1).reshape(x.shape)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    assert (np.abs(np.asarray(y)).max(-1) > 0).all()
+    assert jnp.all(jnp.isfinite(y)) and float(aux) > 0
 
 
 # ---------------------------------------------------------------------------
